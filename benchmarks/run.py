@@ -231,7 +231,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma list: fig6,fig7,fig8,fig9,fig10,fig11,fig12,"
-                         "asha,roofline,train,soa_kernel,ledger,service")
+                         "asha,roofline,train,ledger,service")
     ap.add_argument("--json", nargs="?", const="BENCH_simcore.json",
                     default=None, metavar="PATH",
                     help="write a JSON benchmark record (default "
@@ -253,6 +253,9 @@ def main() -> None:
                          "the CHANGES.md entry count)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.exact:
         os.environ["REPRO_EXACT_TICKS"] = "1"
     elif os.environ.pop("REPRO_EXACT_TICKS", None):
@@ -264,8 +267,7 @@ def main() -> None:
     from benchmarks import (asha_compare, fig6_profiling, fig7_cost_perf,
                             fig8_theta, fig9_refund, fig10_revpred,
                             fig11_earlycurve, fig12_checkpoint, ledger,
-                            roofline_report, serve_load, soa_kernel,
-                            training_trials)
+                            roofline_report, serve_load, training_trials)
     from repro.core.trial import WORKLOADS
 
     quick_w = WORKLOADS[:2]
@@ -286,7 +288,6 @@ def main() -> None:
         "asha": lambda: asha_compare.run(
             workloads=quick_w[:1] if args.quick else None),
         "roofline": lambda: roofline_report.run(),
-        "soa_kernel": lambda: soa_kernel.run(quick=args.quick),
         "ledger": lambda: ledger.run(quick=args.quick),
         "train": lambda: training_trials.run(quick=args.quick),
         "service": lambda: serve_load.run(quick=args.quick),

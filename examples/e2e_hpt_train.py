@@ -28,6 +28,7 @@ import argparse
 import time
 
 from repro.backends.training import TRAINING_ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sweep.runner import SweepRunner
 from repro.sweep.spec import ScenarioSpec
 
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--market-seed", type=int, default=0)
     ap.add_argument("--days", type=float, default=2.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = ScenarioSpec(workload=args.arch, market_seed=args.market_seed,
                         scheduler=args.scheduler, theta=args.theta,

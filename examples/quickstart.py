@@ -15,6 +15,7 @@ from repro.core.market import SpotMarket
 from repro.core.orchestrator import run_single_spot_baseline
 from repro.core.revpred import OracleRevPred
 from repro.core.trial import WORKLOADS, SimTrialBackend, make_trials
+from repro.launch.compile_cache import enable_compile_cache
 from repro.tuner import (ASHAScheduler, GridSearcher, SpotTuneScheduler,
                          Tuner, build_engine)
 
@@ -26,6 +27,7 @@ def fresh_engine(seed_market: int = 3, seed: int = 0):
 
 
 def main():
+    enable_compile_cache()
     workload = WORKLOADS[0]  # LoR benchmark (Table II analogue)
     print(f"workload={workload.name}: {len(workload.hp_grid())} HP settings, "
           f"max_trial_steps={workload.max_trial_steps}")

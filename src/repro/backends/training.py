@@ -153,13 +153,16 @@ def _step_cost(binding: TrainingBinding, bs: int) -> tuple:
     model = Model(cfg)
     optimizer = adamw(3e-3, keep_master=(cfg.opt_precision == "fp32"))
     ctx = null_ctx(attn_chunk=min(512, binding.seq), remat="none")
-    state_shapes = jax.eval_shape(
-        lambda: init_state(model, optimizer, binding.seed))
-    batch = SyntheticLMDataset(cfg, bs, binding.seq,
-                               seed=binding.seed).get_batch(0)
-    batch_shapes = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype),
-        batch)
+    # module_cost reads the CPU backend's HLO (a TPU compile hides its dots
+    # in fusions it does not count): compile for the CPU wherever this runs,
+    # so the simulated instance's step time does not depend on the host
+    cpu = jax.sharding.SingleDeviceSharding(jax.devices("cpu")[0])
+    on_cpu = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=cpu), t)
+    state_shapes = on_cpu(jax.eval_shape(
+        lambda: init_state(model, optimizer, binding.seed)))
+    batch_shapes = on_cpu(SyntheticLMDataset(
+        cfg, bs, binding.seq, seed=binding.seed).get_batch(0))
     step = make_train_step(model, optimizer, ctx)
     text = jax.jit(step).lower(state_shapes, batch_shapes).compile().as_text()
     cost = module_cost(text, 1)

@@ -15,26 +15,39 @@ from repro.kernels import ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
-@functools.partial(jax.jit, static_argnames=("force",))
-def _lstm_ref_jit(x, h, c, w_ih, w_hh, b, force=None):
-    return ref.lstm_cell_ref(x, h, c, w_ih, w_hh, b)
+    return jax.default_backend() == "tpu"
 
 
 def lstm_cell(x, h, c, w_ih, w_hh, b, force: str | None = None):
-    """Fused LSTM cell.  force: None (auto) | 'ref' | 'pallas' | 'interpret'."""
+    """Fused LSTM cell.  force: None (auto) | 'ref' | 'pallas' | 'interpret'.
+
+    Differentiable in every mode: the kernel paths take their gradient from
+    the jnp reference (``_lstm_cell_kernel``'s custom VJP)."""
     mode = force or ("pallas" if _on_tpu() else "ref")
     if mode == "ref":
         return ref.lstm_cell_ref(x, h, c, w_ih, w_hh, b)
+    return _lstm_cell_kernel(x, h, c, w_ih, w_hh, b, mode == "interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _lstm_cell_kernel(x, h, c, w_ih, w_hh, b, interpret):
     from repro.kernels import lstm_cell as klc
 
-    return klc.lstm_cell_pallas(x, h, c, w_ih, w_hh, b,
-                                interpret=(mode == "interpret"))
+    return klc.lstm_cell_pallas(x, h, c, w_ih, w_hh, b, interpret=interpret)
+
+
+def _lstm_cell_kernel_fwd(x, h, c, w_ih, w_hh, b, interpret):
+    out = _lstm_cell_kernel(x, h, c, w_ih, w_hh, b, interpret)
+    return out, (x, h, c, w_ih, w_hh, b)
+
+
+def _lstm_cell_kernel_bwd(interpret, res, g):
+    # pallas_call has no VJP: differentiate the oracle at the saved inputs
+    _, vjp = jax.vjp(ref.lstm_cell_ref, *res)
+    return vjp(g)
+
+
+_lstm_cell_kernel.defvjp(_lstm_cell_kernel_fwd, _lstm_cell_kernel_bwd)
 
 
 def flash_attention(q, k, v, causal: bool = True, force: str | None = None,

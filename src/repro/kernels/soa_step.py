@@ -3,31 +3,16 @@
 The SoA stepper's per-round compute is (a) folding every touched row's
 deterministic per-tick step-time observations into its perf-matrix EWMA
 entry and (b) the segmented min over the per-row next-boundary ticks that
-replaces the engines' heaps.  The numpy paths below are the default and the
-reference: the columnwise masked fold is bit-exact to the sequential
+replaces the engines' heaps.  The columnwise masked fold is bit-exact to the sequential
 per-observation ``PerfModel.update_many`` replay (same per-row op order,
 elementwise float64), and the boundary scan is one ``np.minimum.reduceat``.
 
-``REPRO_SOA_PALLAS=1`` opts the fold into the fused Pallas kernel
-(``soa_step_fused``), which computes both halves in a single ``pallas_call``.
-On this container (CPU) the kernel runs in interpreter mode — useful for
-validation, not speed; on TPU it compiles natively (float64 inputs would
-need an f32 retune there, which is why numpy stays the default).
-``tests/test_kernels.py`` pins kernel == reference.
-
-One subtlety: XLA contracts ``b*m + a*col`` into an FMA, which rounds
-once where numpy rounds twice.  The kernel paths stay bit-exact anyway
-because ``PerfModel.ewma`` defaults to 0.5 — both products are exact
-exponent shifts, so the contraction has nothing to re-round.  A
-non-dyadic ewma could drift by 1 ulp per fold step under the Pallas
-paths; the numpy default path is exact for any alpha.
-
+``ewma_fold_sorted`` is the stepper's fold and ``ewma_fold_ref`` its test
+reference.  Both stay on the host: the bit-exact contract needs float64,
+which the TPU's Pallas backend does not compile.
 """
 
 from __future__ import annotations
-
-import os
-from typing import Optional, Tuple
 
 import numpy as np
 
@@ -91,134 +76,3 @@ def segmented_min_ref(next_k: np.ndarray, starts: np.ndarray) -> np.ndarray:
     """Per-segment min of ``next_k`` over contiguous ``starts`` segments —
     the "next boundary" scan (``_BIG`` rows are the not-running padding)."""
     return np.minimum.reduceat(next_k, starts)
-
-
-# ------------------------------------------------------------------- pallas
-def _pallas_enabled() -> bool:
-    if os.environ.get("REPRO_SOA_PALLAS", "0") in ("", "0"):
-        return False
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        return True
-    except Exception:  # pragma: no cover - pallas baked into this toolchain
-        return False
-
-
-_FUSED = None
-
-
-def _build_fused():
-    """Build the fused fold + boundary-scan pallas_call (one dispatch)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    def kernel(obs_ref, lens_ref, m0_ref, first_ref, ewma_ref,
-               nk_ref, rep_ref, m_out, seg_out):
-        a = ewma_ref[:]
-        b = 1.0 - a
-        lens = lens_ref[:]
-        first = first_ref[:]
-
-        def fold(j, carry):
-            m, fr = carry
-            col = obs_ref[:, j]
-            valid = j < lens
-            m = jnp.where(valid & fr, col,
-                          jnp.where(valid, b * m + a * col, m))
-            return m, fr & ~valid
-
-        m0 = jnp.where(first, 0.0, m0_ref[:])
-        m, _ = jax.lax.fori_loop(0, obs_ref.shape[1], fold, (m0, first))
-        m_out[:] = m
-        seg_out[:] = jnp.full(seg_out.shape, _BIG, seg_out.dtype)
-
-        def smin(i, _):
-            rr = rep_ref[i]
-            cur = pl.load(seg_out, (pl.dslice(rr, 1),))
-            val = pl.load(nk_ref, (pl.dslice(i, 1),))
-            pl.store(seg_out, (pl.dslice(rr, 1),), jnp.minimum(cur, val))
-            return 0
-
-        jax.lax.fori_loop(0, nk_ref.shape[0], smin, 0)
-
-    interpret = jax.default_backend() != "tpu"
-
-    def fused(obs, lens, m0, first, ewma, next_k, row_rep, n_reps):
-        call = pl.pallas_call(
-            kernel,
-            out_shape=(jax.ShapeDtypeStruct(m0.shape, jnp.float64),
-                       jax.ShapeDtypeStruct((n_reps,), jnp.int64)),
-            interpret=interpret,
-        )
-        m, seg = call(jnp.asarray(obs), jnp.asarray(lens),
-                      jnp.asarray(m0), jnp.asarray(first),
-                      jnp.asarray(ewma), jnp.asarray(next_k),
-                      jnp.asarray(row_rep))
-        return np.asarray(m), np.asarray(seg)
-
-    return fused
-
-
-def soa_step_fused(obs, lens, m0, first, ewma, next_k, row_rep,
-                   n_reps: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Fused inner step: (EWMA fold, segmented boundary min) in one kernel
-    dispatch.  Requires pallas (REPRO_SOA_PALLAS=1 path and the kernel
-    test); the stepper's default splits the halves across the numpy refs."""
-    global _FUSED
-    if _FUSED is None:
-        _FUSED = _build_fused()
-    return _FUSED(obs, lens, m0, first, ewma, next_k, row_rep, n_reps)
-
-
-# ----------------------------------------------------------------- dispatch
-_USE_PALLAS: Optional[bool] = None
-
-
-def _use_pallas() -> bool:
-    global _USE_PALLAS
-    if _USE_PALLAS is None:
-        _USE_PALLAS = _pallas_enabled()
-    return _USE_PALLAS
-
-
-def ewma_fold(obs, lens, m0, first, ewma) -> np.ndarray:
-    """Dispatching fold: numpy reference by default, the Pallas kernel's
-    fold half under REPRO_SOA_PALLAS=1 (both bit-exact to sequential)."""
-    if _use_pallas():
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        def kernel(obs_ref, lens_ref, m0_ref, first_ref, ewma_ref, m_out):
-            a = ewma_ref[:]
-            b = 1.0 - a
-            lens_v = lens_ref[:]
-
-            def fold(j, carry):
-                m, fr = carry
-                col = obs_ref[:, j]
-                valid = j < lens_v
-                m = jnp.where(valid & fr, col,
-                              jnp.where(valid, b * m + a * col, m))
-                return m, fr & ~valid
-
-            m0v = jnp.where(first_ref[:], 0.0, m0_ref[:])
-            m, _ = jax.lax.fori_loop(0, obs_ref.shape[1], fold,
-                                     (m0v, first_ref[:]))
-            m_out[:] = m
-
-        out = pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct(m0.shape, jnp.float64),
-            interpret=jax.default_backend() != "tpu",
-        )(jnp.asarray(obs), jnp.asarray(lens), jnp.asarray(m0),
-          jnp.asarray(first), jnp.asarray(ewma))
-        return np.asarray(out)
-    return ewma_fold_sorted(obs, lens, m0, first, ewma)
-
-
-def segmented_min(next_k, starts) -> np.ndarray:
-    """Dispatching boundary scan (numpy reduceat; the fused kernel's scatter
-    half covers the Pallas path and is pinned equal by the kernel test)."""
-    return segmented_min_ref(next_k, starts)

@@ -207,8 +207,6 @@ def lower_cell(arch: str, shape_name: str, mesh, verbose: bool = True):
     t_compile = time.monotonic() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # pre-0.6 jax: one dict per program
-        cost = cost[0] if cost else {}
     mem = _mem_dict(compiled)
     hlo_text = compiled.as_text()
     # loop-aware exact cost (cost_analysis counts while bodies once — see

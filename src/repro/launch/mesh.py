@@ -5,6 +5,14 @@ module never touches jax device state (required: the dry-run sets
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """Mesh with ``Auto`` axes: the sharding rules place arrays through
+    ``with_sharding_constraint``, which ``make_mesh``'s default
+    ``Explicit`` axes reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,12 +23,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     DESIGN.md §3)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_small_mesh(shape=(2, 4), axes=("data", "model")):
     """Reduced mesh for CI-sized dry-run tests (8 host devices)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def data_axes_of(mesh) -> tuple:

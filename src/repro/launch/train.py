@@ -102,7 +102,11 @@ class Trainer:
         the metric stream reloads from the manifest so the trial continues
         the original stream exactly."""
         assert self.ckpt is not None
-        like = jax.tree.map(lambda x: x, self.state)
+        # drop the current state before reading: a full-width state and its
+        # restored copy do not fit one chip together
+        like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                            self.state)
+        self.state = None
         self.state, step = self.ckpt.restore(like, step=step,
                                              sharding_fn=sharding_fn)
         self.step = step

@@ -1,10 +1,6 @@
-"""shard_map compatibility across the JAX versions this repo meets.
+"""``shard_map`` with the replication check off.
 
-Two renames happened upstream: ``shard_map`` moved from
-``jax.experimental.shard_map`` to the top-level namespace, and the
-replication-check kwarg went ``check_rep`` -> ``check_vma`` (jax >= 0.6).
-``shard_map_unchecked`` is shard_map with that check disabled under either
-name — every distributed body in this repo returns pmean'd/psum'd values the
+Every distributed body in this repo returns pmean'd/psum'd values the
 checker cannot see through, so they all disable it.
 """
 
@@ -12,16 +8,7 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # pre-0.6: the kwarg is check_rep
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)

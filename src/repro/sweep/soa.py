@@ -19,8 +19,7 @@ the per-replica "next boundary" scan is a segmented ``np.minimum.reduceat``
 over it.  Everything else is gathered fresh from the authoritative
 ``TrialState`` objects for the rows actually touched in a round, so there is
 no second copy of simulation state to keep coherent.  The EWMA fold and the
-segmented min run through ``repro.kernels.soa_step`` (numpy reference by
-default; the fused Pallas kernel takes over under REPRO_SOA_PALLAS=1).
+segmented min are the float64 numpy routines of ``repro.kernels.soa_step``.
 
 The round's lifecycle work is batched too (``_lifecycle``): every touched
 row's event is classified in one vectorized pass (``classify_rows`` — the
@@ -52,8 +51,7 @@ import numpy as np
 from repro.core.market import HOUR
 from repro.core.provisioner import best_fused_multi
 from repro.core.trial import SimTrialBackend, _jitter_entry
-from repro.kernels.soa_step import (_use_pallas, ewma_fold, segmented_min,
-                                    soa_step_fused)
+from repro.kernels.soa_step import ewma_fold_sorted, segmented_min_ref
 from repro.sweep.runner import SweepRunner
 from repro.tuner.engine import (ProvisionBatch, Status,
                                 preview_boundary_batch)
@@ -143,8 +141,6 @@ class SoaSweep:
             type(e.backend).noisy_step_times
             is SimTrialBackend.noisy_step_times for e in self.engines)
         self._seg5: Optional[np.ndarray] = None   # stage-5 boundary scan memo
-        self._pending_fold: Optional[tuple] = None
-        self._defer_fold = False
         R = len(self.tuners)
         self.R = R
         self.t = np.zeros(R)
@@ -268,7 +264,7 @@ class SoaSweep:
             np.int64)
         seg_min = self._seg5      # stage 5's scan, still valid when nothing
         if seg_min is None:       # touched next_k since (rebuilds invalidate)
-            seg_min = segmented_min(self.next_k, self.rep_start)
+            seg_min = segmented_min_ref(self.next_k, self.rep_start)
         self._seg5 = None
         runnable = (seg_min < _BIG) | self.has_waiting
         # idle replicas first (the engine returns before its horizon check)
@@ -289,19 +285,9 @@ class SoaSweep:
         k_now_rows = self.k_now[self.row_rep]
         touched = np.nonzero(act_mask[self.row_rep]
                              & (self.next_k <= k_now_rows))[0]
-        # Pallas rounds defer the fold into the fused stage-5 kernel, but
-        # only when every touched replica is on the table path (decision
-        # tables never read the perf matrix; the scalar chain's dispatches
-        # may)
-        self._defer_fold = bool(
-            len(touched) and _use_pallas()
-            and self._table_rep[self.row_rep[touched]].all())
         new_points, sts = self._advance_rows(touched)
         self._lifecycle(touched, new_points, sts)
-        # 3. deploys (batched across replicas like the generator path); a
-        # deferred fold must land first — the Eq.-2 solve reads the matrix
-        if self._pending_fold is not None and self.has_waiting[act].any():
-            self._flush_fold()
+        # 3. deploys (batched across replicas like the generator path)
         deployed = self._deploys(act)
         # 4. boundary recompute for rows still/newly running
         recompute = [int(i) for i in touched
@@ -309,16 +295,8 @@ class SoaSweep:
         seen = set(recompute)
         recompute += [i for i in deployed if i not in seen]
         self._recompute(recompute)
-        # 5. next boundary per replica (the heap-pop equivalent); with a
-        # fold still parked, one fused kernel dispatch does both halves
-        if self._pending_fold is not None:
-            pad, lens, m0, first, ew, perfs, keys = self._pending_fold
-            self._pending_fold = None
-            m, seg_min = soa_step_fused(pad, lens, m0, first, ew,
-                                        self.next_k, self.row_rep, self.R)
-            self._scatter_fold(m, perfs, keys, first)
-        else:
-            seg_min = segmented_min(self.next_k, self.rep_start)
+        # 5. next boundary per replica (the heap-pop equivalent)
+        seg_min = segmented_min_ref(self.next_k, self.rep_start)
         self._seg5 = seg_min
         km = seg_min[act]
         kn = self.k_now[act]
@@ -391,8 +369,7 @@ class SoaSweep:
             live, np.minimum(steps0 + (t - start) / spt, target), steps0)
         lidx = np.nonzero(live)[0]
         if len(lidx):
-            self._fold_perf(sts, reps, lidx, k0, k1, tick, spt,
-                            defer=self._defer_fold)
+            self._fold_perf(sts, reps, lidx, k0, k1, tick, spt)
         # steps as of the previous tick — what an every-tick scan had seen
         lim = (k1 - 1) * tick
         s_prev = np.where(lim <= start, steps0,
@@ -430,15 +407,13 @@ class SoaSweep:
             out[j] = [(s, v) for s, v in zip(new_steps, vals) if s > sp]
         return out, sts
 
-    def _fold_perf(self, sts, reps, lidx, k0, k1, tick, spt,
-                   defer: bool = False) -> None:
+    def _fold_perf(self, sts, reps, lidx, k0, k1, tick, spt) -> None:
         """Perf-matrix catch-up for the live rows: each row's jitter
         observations are sliced straight from the shared jitter cache into
         one padded matrix (the same float64 products ``noisy_step_times``
         returns, minus one array allocation per row), then folded
-        columnwise — or, with ``defer`` (Pallas round fusion), parked for
-        one fused fold+boundary-scan dispatch at round end.  Bit-exact
-        replay of ``PerfModel.update_many`` per row either way."""
+        columnwise.  Bit-exact replay of ``PerfModel.update_many`` per
+        row."""
         n_live = len(lidx)
         engines = self.engines
         lidx_l = lidx.tolist()
@@ -495,22 +470,7 @@ class SoaSweep:
             else:
                 first[o] = True
             ew[o] = perf.ewma
-        if defer:
-            self._pending_fold = (pad, lens, m0, first, ew, perfs, keys)
-            return
-        m = ewma_fold(pad, lens, m0, first, ew)
-        self._scatter_fold(m, perfs, keys, first)
-
-    def _flush_fold(self) -> None:
-        """Fold a parked Pallas-round batch now (a deploy solve is about
-        to read the perf matrix)."""
-        pad, lens, m0, first, ew, perfs, keys = self._pending_fold
-        self._pending_fold = None
-        m = ewma_fold(pad, lens, m0, first, ew)
-        self._scatter_fold(m, perfs, keys, first)
-
-    @staticmethod
-    def _scatter_fold(m, perfs, keys, first) -> None:
+        m = ewma_fold_sorted(pad, lens, m0, first, ew)
         for o in range(len(keys)):
             perfs[o]._m[keys[o]] = float(m[o])
             if first[o]:

@@ -17,6 +17,7 @@ from repro.kernels import ops
     (4, 6, 32, 4, 16),
     (8, 7, 64, 4, 32),
     (2, 13, 16, 2, 16),
+    (6, 6, 32, 4, 32),          # B not a multiple of the block: padded
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_lstm_cell_sweep(B, I, H, bb, bh, dtype, rng):
@@ -89,21 +90,64 @@ def test_ops_dispatch_cpu_uses_ref(rng):
     np.testing.assert_allclose(np.asarray(h3), np.asarray(h2), rtol=1e-5, atol=1e-5)
 
 
-# =================================================== SoA inner-step kernels
-# Three layers, each bit-exact to the one below (repro.kernels.soa_step):
-#
-#     soa_step_fused (pallas, one dispatch)
-#         == ewma_fold_sorted / segmented_min_ref   (numpy, the default)
-#         == ewma_fold_ref                          (columnwise masked fold)
-#         == PerfModel.update_many called per row   (production semantics)
-#
-# The Pallas check runs in a subprocess with JAX_ENABLE_X64=1: the fold is
-# float64 and the repo never flips x64 process-wide (the training backends
-# are float32), so an in-process check would silently downcast.
+@pytest.mark.parametrize("B", [1, 130])
+def test_lstm_cell_kernel_grad_matches_ref(B, rng):
+    """jax.grad through the kernel path (custom VJP) == grad through the
+    oracle, over two chained steps so the kernel's forward feeds the second
+    step's backward."""
+    I, H = 6, 32
+    f32 = lambda *shape, s=1.0: jnp.asarray(rng.standard_normal(shape) * s,
+                                            jnp.float32)
+    xs = f32(2, B, I)
+    h0, c0 = f32(B, H), f32(B, H)
+    w = (f32(I, 4 * H, s=0.3), f32(H, 4 * H, s=0.3), f32(4 * H, s=0.1))
+    proj = f32(B, H)
 
-import os
-import subprocess
-import sys
+    def loss(w, h, c, cell):
+        for t in range(2):
+            h, c = cell(xs[t], h, c, *w)
+        return jnp.sum(h * proj) + jnp.sum(jnp.tanh(c))
+
+    kern = lambda *a: ops.lstm_cell(*a, force="interpret")
+    g_kern = jax.grad(loss, argnums=(0, 1, 2))(w, h0, c0, kern)
+    g_ref = jax.grad(loss, argnums=(0, 1, 2))(w, h0, c0, ref.lstm_cell_ref)
+    for a, b in zip(jax.tree.leaves(g_kern), jax.tree.leaves(g_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_revpred_train_step_through_interpret_kernel(monkeypatch, rng):
+    """One ``train_model`` step of ``revpred_logits`` with every LSTM cell
+    on the kernel path (interpret mode) lands on the same parameters as the
+    jnp-oracle step."""
+    import functools
+
+    from repro.core import revpred as rp
+
+    n = 8
+    data = {"hist": rng.uniform(0, 1, (n, rp.HISTORY, rp.N_FEAT)).astype(np.float32),
+            "present": rng.uniform(0, 1, (n, rp.N_FEAT + 1)).astype(np.float32),
+            "label": (np.arange(n) % 3 == 0).astype(np.float32)}
+    init = rp.init_revpred(jax.random.key(0))
+    want, _ = rp.train_model(rp.revpred_logits, init, data, epochs=1, bs=n)
+    monkeypatch.setattr(ops, "lstm_cell",
+                        functools.partial(ops.lstm_cell, force="interpret"))
+    got, _ = rp.train_model(rp.revpred_logits, init, data, epochs=1, bs=n)
+    moved = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
+                         want, init)
+    assert max(jax.tree.leaves(moved)) > 1e-4      # the step did train
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+# =================================================== SoA inner-step kernels
+# Two layers, each bit-exact to the one below (repro.kernels.soa_step):
+#
+#     ewma_fold_sorted / segmented_min_ref      (numpy, the stepper's path)
+#         == ewma_fold_ref                      (columnwise masked fold)
+#         == PerfModel.update_many called per row   (production semantics)
+
 import types
 
 from repro.core.provisioner import PerfModel
@@ -219,62 +263,3 @@ def test_jitter_entry_batch_fill_equals_scalar_fill():
         trial._vec_seed_ok = orig
         trial._JITTER_CACHE.clear()
     assert np.array_equal(fast, slow)
-
-
-_PALLAS_SCRIPT = r"""
-import importlib.util
-import numpy as np
-if importlib.util.find_spec("jax") is None or \
-        importlib.util.find_spec("jax.experimental.pallas") is None:
-    print("SKIP: pallas unavailable")
-    raise SystemExit(0)
-import os
-os.environ["REPRO_SOA_PALLAS"] = "1"
-from repro.kernels.soa_step import (ewma_fold, ewma_fold_ref,
-                                    segmented_min_ref, soa_step_fused)
-_BIG = np.int64(1) << np.int64(60)
-rng = np.random.default_rng(42)
-rows, width = 37, 23
-lens = rng.integers(0, width + 1, rows)
-obs = rng.uniform(0.5, 12.0, (rows, width))
-m0 = rng.uniform(0.5, 12.0, rows)
-first = rng.random(rows) < 0.4
-ewma = np.full(rows, 0.5)
-row_rep = np.sort(rng.integers(0, 5, rows)).astype(np.int64)
-next_k = rng.integers(0, 1_000_000, rows).astype(np.int64)
-next_k[rng.random(rows) < 0.3] = _BIG
-m_ref = ewma_fold_ref(obs, lens, m0, first, ewma)
-starts = np.searchsorted(row_rep, np.arange(5)).astype(np.int64)
-seg_ref = segmented_min_ref(next_k, starts)
-m, seg = soa_step_fused(obs, lens, m0, first, ewma, next_k, row_rep, 5)
-assert np.array_equal(m, m_ref), (m - m_ref)
-assert np.array_equal(seg, seg_ref), (seg, seg_ref)
-m2 = ewma_fold(obs, lens, m0, first, ewma)   # dispatch honors the env flag
-assert np.array_equal(m2, m_ref), (m2 - m_ref)
-# decoupled shapes: the stepper folds only the round's live rows (F) while
-# the boundary scan covers every segment row (N != F)
-N = 113
-row_rep2 = np.sort(rng.integers(0, 9, N)).astype(np.int64)
-next_k2 = rng.integers(0, 1_000_000, N).astype(np.int64)
-next_k2[rng.random(N) < 0.4] = _BIG
-starts2 = np.searchsorted(row_rep2, np.arange(9)).astype(np.int64)
-m3, seg3 = soa_step_fused(obs, lens, m0, first, ewma, next_k2, row_rep2, 9)
-assert np.array_equal(m3, m_ref), (m3 - m_ref)
-assert np.array_equal(seg3, segmented_min_ref(next_k2, starts2))
-print("OK")
-"""
-
-
-def test_soa_step_fused_pallas_interpret_matches_refs():
-    """The fused pallas_call (interpret mode on CPU) == both numpy refs."""
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env = dict(os.environ, JAX_ENABLE_X64="1")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [src] + env.get("PYTHONPATH", "").split(os.pathsep))
-    proc = subprocess.run([sys.executable, "-c", _PALLAS_SCRIPT],
-                          capture_output=True, text=True, env=env,
-                          timeout=300)
-    if "SKIP" in proc.stdout:
-        pytest.skip("pallas unavailable in this environment")
-    assert proc.returncode == 0 and "OK" in proc.stdout, \
-        proc.stdout + proc.stderr

@@ -216,51 +216,6 @@ def test_soa_equals_per_replica_policy_grid(policy):
     assert not diffs, "\n".join(diffs[:12])
 
 
-# ----------------------------------------------------- Pallas fused rounds
-
-_PALLAS_SWEEP_SCRIPT = r"""
-import importlib.util
-if importlib.util.find_spec("jax") is None or \
-        importlib.util.find_spec("jax.experimental.pallas") is None:
-    print("SKIP: pallas unavailable")
-    raise SystemExit(0)
-from repro.sweep import scenario_grid
-from repro.tuner.equivalence import compare_sweep_modes
-from repro.kernels import soa_step
-specs = scenario_grid(["LoR", "SVM"], [3, 11], revpred="oracle",
-                      theta=0.7, days=8.0, scheduler="spottune")
-diffs = compare_sweep_modes(specs)
-assert not diffs, "\n".join(diffs[:10])
-# the fused kernel must actually have been dispatched, or this proved nothing
-assert soa_step._use_pallas() and soa_step._FUSED is not None
-print("OK")
-"""
-
-
-def test_soa_pallas_fused_rounds_equal_generator():
-    """Whole-sweep validation of the fused Pallas round (interpret mode):
-    REPRO_SOA_PALLAS=1 routes the stepper's EWMA fold + boundary scan
-    through one ``soa_step_fused`` dispatch per round (deferred across the
-    deploy stage), and the outcome must stay bit-exact against the
-    generator path.  Subprocess with JAX_ENABLE_X64=1 — the fold is
-    float64 and the repo never flips x64 process-wide."""
-    import os
-    import subprocess
-    import sys
-
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env = dict(os.environ, JAX_ENABLE_X64="1", REPRO_SOA_PALLAS="1")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [src] + env.get("PYTHONPATH", "").split(os.pathsep))
-    proc = subprocess.run([sys.executable, "-c", _PALLAS_SWEEP_SCRIPT],
-                          capture_output=True, text=True, env=env,
-                          timeout=500)
-    if "SKIP" in proc.stdout:
-        pytest.skip("pallas unavailable in this environment")
-    assert proc.returncode == 0 and "OK" in proc.stdout, \
-        proc.stdout + proc.stderr
-
-
 # ------------------------------------------------------ Δt deploy batching
 
 @pytest.mark.parametrize("window", [60.0, 600.0])
