@@ -1,0 +1,10 @@
+"""device_idle_share.train: the share of the traced window in which no
+operation ran on the device, 1 - busy / window, averaged over the chips,
+in %."""
+
+
+def read(run):
+    t = run["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
