@@ -21,11 +21,12 @@ from __future__ import annotations
 import io
 import json
 import threading
-import time
 from typing import Any, Callable, Optional
 
 import jax
 import numpy as np
+
+from repro import telemetry
 
 MANIFEST = "MANIFEST.json"
 
@@ -44,33 +45,44 @@ def tree_bytes(tree) -> int:
 
 def save_pytree(store, prefix: str, step: int, tree, blocking: bool = True,
                 extra_meta: Optional[dict] = None):
-    """Serialize a pytree.  Returns a handle with .wait() (async support)."""
-    leaves, treedef = jax.tree.flatten(tree)
-    host_leaves = [np.asarray(l) for l in leaves]   # device->host before thread
-    meta = {
-        "treedef": str(treedef),
-        "n_leaves": len(leaves),
-        "step": step,
-        "shapes": [list(l.shape) for l in host_leaves],
-        "dtypes": [str(l.dtype) for l in host_leaves],
-        "keys": [k for k, _ in _leaf_paths(tree)],
-        "extra": extra_meta or {},
-    }
+    """Serialize a pytree.  Returns a handle with .wait() (async support).
 
-    def write():
-        base = f"{prefix}/step_{step:08d}"
-        for i, arr in enumerate(host_leaves):
-            # raw buffers (not np.save): numpy can't serialize ml_dtypes
-            # (bfloat16); shape/dtype live in the manifest
-            store.put(f"{base}/leaf_{i:05d}.npy", arr.tobytes())
-        store.put(f"{base}/{MANIFEST}", json.dumps(meta).encode())
+    Spans (``repro.telemetry``): ``ckpt.save`` around the call, with
+    ``ckpt.save.to_host``, then per leaf ``ckpt.save.serialize`` and
+    ``ckpt.save.put``, then ``ckpt.save.manifest`` (on the writer thread
+    when not blocking, still children of ``ckpt.save``)."""
+    with telemetry.span("ckpt.save") as save_id:
+        leaves, treedef = jax.tree.flatten(tree)
+        with telemetry.span("ckpt.save.to_host"):
+            host_leaves = [np.asarray(l) for l in leaves]   # device->host before thread
+        meta = {
+            "treedef": str(treedef),
+            "n_leaves": len(leaves),
+            "step": step,
+            "shapes": [list(l.shape) for l in host_leaves],
+            "dtypes": [str(l.dtype) for l in host_leaves],
+            "keys": [k for k, _ in _leaf_paths(tree)],
+            "extra": extra_meta or {},
+        }
 
-    if blocking:
-        write()
-        return _DoneHandle()
-    t = threading.Thread(target=write, daemon=True)
-    t.start()
-    return _ThreadHandle(t)
+        def write():
+            base = f"{prefix}/step_{step:08d}"
+            for i, arr in enumerate(host_leaves):
+                # raw buffers (not np.save): numpy can't serialize ml_dtypes
+                # (bfloat16); shape/dtype live in the manifest
+                with telemetry.span("ckpt.save.serialize", parent=save_id):
+                    data = arr.tobytes()
+                with telemetry.span("ckpt.save.put", parent=save_id):
+                    store.put(f"{base}/leaf_{i:05d}.npy", data)
+            with telemetry.span("ckpt.save.manifest", parent=save_id):
+                store.put(f"{base}/{MANIFEST}", json.dumps(meta).encode())
+
+        if blocking:
+            write()
+            return _DoneHandle()
+        t = threading.Thread(target=write, daemon=True)
+        t.start()
+        return _ThreadHandle(t)
 
 
 class _DoneHandle:
@@ -111,28 +123,42 @@ def restore_pytree(store, prefix: str, like, step: Optional[int] = None,
                    sharding_fn: Optional[Callable[[Any], Any]] = None):
     """Restore into the structure of ``like`` (a pytree of arrays or
     ShapeDtypeStructs).  ``sharding_fn(leaf_template) -> Sharding`` enables
-    elastic re-shard onto a new mesh.  Returns (tree, step)."""
-    if step is None:
-        step = latest_step(store, prefix)
-        if step is None:
-            raise FileNotFoundError(f"no checkpoint under {prefix}")
-    base = f"{prefix}/step_{step:08d}"
-    meta = json.loads(store.get(f"{base}/{MANIFEST}").decode())
-    leaves_like, treedef = jax.tree.flatten(like)
-    assert meta["n_leaves"] == len(leaves_like), (
-        f"checkpoint has {meta['n_leaves']} leaves, template has {len(leaves_like)}")
-    out = []
-    for i, tmpl in enumerate(leaves_like):
-        import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
+    elastic re-shard onto a new mesh.  Returns (tree, step).
 
-        dt = np.dtype(meta["dtypes"][i])
-        arr = np.frombuffer(store.get(f"{base}/leaf_{i:05d}.npy"),
-                            dtype=dt).reshape(meta["shapes"][i])
-        assert list(arr.shape) == list(tmpl.shape), (i, arr.shape, tmpl.shape)
-        if sharding_fn is not None:
-            out.append(jax.device_put(arr.astype(tmpl.dtype), sharding_fn(tmpl)))
-        else:
-            out.append(jax.numpy.asarray(arr.astype(tmpl.dtype)))
+    Spans (``repro.telemetry``): ``ckpt.restore`` around the call, with
+    ``ckpt.restore.manifest`` (finding and reading it), then per leaf
+    ``ckpt.restore.get``, ``ckpt.restore.decode`` and
+    ``ckpt.restore.to_device``; counter ``ckpt.host_copy_bytes`` (bytes
+    copied on the host beyond those read)."""
+    with telemetry.span("ckpt.restore"):
+        with telemetry.span("ckpt.restore.manifest"):
+            if step is None:
+                step = latest_step(store, prefix)
+                if step is None:
+                    raise FileNotFoundError(f"no checkpoint under {prefix}")
+            base = f"{prefix}/step_{step:08d}"
+            meta = json.loads(store.get(f"{base}/{MANIFEST}").decode())
+        leaves_like, treedef = jax.tree.flatten(like)
+        assert meta["n_leaves"] == len(leaves_like), (
+            f"checkpoint has {meta['n_leaves']} leaves, template has {len(leaves_like)}")
+        out = []
+        for i, tmpl in enumerate(leaves_like):
+            import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
+
+            with telemetry.span("ckpt.restore.get"):
+                data = store.get(f"{base}/leaf_{i:05d}.npy")
+            with telemetry.span("ckpt.restore.decode"):
+                arr = np.frombuffer(data, dtype=np.dtype(meta["dtypes"][i])
+                                    ).reshape(meta["shapes"][i])
+                assert list(arr.shape) == list(tmpl.shape), (i, arr.shape, tmpl.shape)
+                host = arr.astype(tmpl.dtype)
+                if not np.may_share_memory(host, arr):
+                    telemetry.count("ckpt.host_copy_bytes", host.nbytes)
+            with telemetry.span("ckpt.restore.to_device"):
+                if sharding_fn is not None:
+                    out.append(jax.device_put(host, sharding_fn(tmpl)))
+                else:
+                    out.append(jax.numpy.asarray(host))
     return jax.tree.unflatten(treedef, out), step
 
 
@@ -146,8 +172,6 @@ class CheckpointManager:
         self.save_interval_steps = save_interval_steps
         self.keep_n = keep_n
         self._pending = None
-        self.saves = 0
-        self.save_seconds = 0.0
 
     def should_save(self, step: int) -> bool:
         return step > 0 and step % self.save_interval_steps == 0
@@ -155,11 +179,8 @@ class CheckpointManager:
     def save(self, step: int, tree, blocking: bool = False, extra_meta=None):
         if self._pending is not None:
             self._pending.wait()  # never two in flight
-        t0 = time.monotonic()
         h = save_pytree(self.store, self.prefix, step, tree,
                         blocking=blocking, extra_meta=extra_meta)
-        self.save_seconds += time.monotonic() - t0
-        self.saves += 1
         self._pending = h
         self._gc()
         return h

@@ -16,6 +16,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint import CheckpointManager
 from repro.data.pipeline import SyntheticLMDataset, prefetch
 from repro.models.context import ModelCtx, null_ctx
@@ -56,15 +57,19 @@ class Trainer:
                  lr_schedule=None, seed: int = 0,
                  ckpt: Optional[CheckpointManager] = None,
                  val_every: int = 10, ctx: Optional[ModelCtx] = None):
-        self.cfg = cfg
-        self.model = Model(cfg)
-        self.optimizer = adamw(lr_schedule if lr_schedule is not None else lr,
-                               keep_master=(cfg.opt_precision == "fp32"))
-        self.ctx = ctx or null_ctx(attn_chunk=min(512, seq), remat="none")
-        self.data = SyntheticLMDataset(cfg, batch, seq, seed=seed)
-        self.step_fn = jax.jit(make_train_step(self.model, self.optimizer, self.ctx),
-                               donate_argnums=(0,))
-        self.state = init_state(self.model, self.optimizer, seed)
+        with telemetry.span("trainer.init"):
+            with telemetry.span("trainer.build"):
+                self.cfg = cfg
+                self.model = Model(cfg)
+                self.optimizer = adamw(lr_schedule if lr_schedule is not None else lr,
+                                       keep_master=(cfg.opt_precision == "fp32"))
+                self.ctx = ctx or null_ctx(attn_chunk=min(512, seq), remat="none")
+                self.data = SyntheticLMDataset(cfg, batch, seq, seed=seed)
+                self.step_fn = jax.jit(
+                    make_train_step(self.model, self.optimizer, self.ctx),
+                    donate_argnums=(0,))
+            with telemetry.span("trainer.init_state"):
+                self.state = init_state(self.model, self.optimizer, seed)
         self.step = 0
         self.ckpt = ckpt
         self.val_every = val_every
@@ -102,23 +107,24 @@ class Trainer:
         the metric stream reloads from the manifest so the trial continues
         the original stream exactly."""
         assert self.ckpt is not None
-        # drop the current state before reading: a full-width state and its
-        # restored copy do not fit one chip together
-        like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                            self.state)
-        self.state = None
-        self.state, step = self.ckpt.restore(like, step=step,
-                                             sharding_fn=sharding_fn)
-        self.step = step
-        import json
+        with telemetry.span("trainer.restore"):
+            # drop the current state before reading: a full-width state and its
+            # restored copy do not fit one chip together
+            like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                                self.state)
+            self.state = None
+            self.state, step = self.ckpt.restore(like, step=step,
+                                                 sharding_fn=sharding_fn)
+            self.step = step
+            import json
 
-        from repro.checkpoint.checkpointer import MANIFEST
+            from repro.checkpoint.checkpointer import MANIFEST
 
-        base = f"{self.ckpt.prefix}/step_{step:08d}"
-        meta = json.loads(self.ckpt.store.get(f"{base}/{MANIFEST}").decode())
-        extra = meta.get("extra", {})
-        self.metrics_steps = list(extra.get("metrics_steps", []))
-        self.metrics_vals = list(extra.get("metrics_vals", []))
+            base = f"{self.ckpt.prefix}/step_{step:08d}"
+            meta = json.loads(self.ckpt.store.get(f"{base}/{MANIFEST}").decode())
+            extra = meta.get("extra", {})
+            self.metrics_steps = list(extra.get("metrics_steps", []))
+            self.metrics_vals = list(extra.get("metrics_vals", []))
         return step
 
     def mean_step_time(self) -> float:
