@@ -435,7 +435,9 @@ class TrainingTrialBackend(TrialBackend):
         snaps = sorted(s for s in run.saved if s <= step)
         if not snaps:
             return None             # fresh start — nothing durable to read
-        like = _to_device(run.state0)
+        # the host copy's shapes and dtypes: no device copy to read them from
+        like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                            run.state0)
         state, got = restore_pytree(self.store, run.prefix, like,
                                     step=snaps[-1],
                                     sharding_fn=self.sharding_fn)

@@ -2,6 +2,9 @@
 a CPU-runnable Trainer used by the HPT examples and
 ``repro.backends.training.TrainingTrialBackend``.
 
+A Trainer builds its random state on first read of ``Trainer.state``; a
+trainer that is restored, or assigned a state, before that never builds it.
+
 The train step is one pjit'd program: loss (vocab-sharded xent + MoE aux) →
 grads → clip → AdamW update.  Fault tolerance comes from the checkpoint
 manager (atomic manifests) + the deterministic data pipeline: restore(step)
@@ -45,12 +48,21 @@ def init_state(model: Model, optimizer: Optimizer, seed: int = 0):
     return {"params": params, "opt": optimizer.init(params)}
 
 
+_UNBUILT = object()     # ``Trainer._state`` before anything reads or assigns it
+
+
 class Trainer:
     """Small real-training loop (CPU-scale configs) with checkpoint/restart.
 
     Used by examples/ and ``repro.backends.training.TrainingTrialBackend``:
     SpotTune treats one Trainer as one HPT trial; ``run_steps`` advances it
     and returns the validation metrics stream the engine/EarlyCurve consume.
+
+    Construction builds the model, optimizer, dataset and the step's ``jit``
+    wrapper, not the state.  The first read of ``state`` builds
+    ``init_state(model, optimizer, seed)`` unless a state was assigned or
+    restored before it, so a resumed trial never makes the random state its
+    restore would replace.
     """
 
     def __init__(self, cfg, batch: int, seq: int, lr: float = 3e-3,
@@ -68,14 +80,28 @@ class Trainer:
                 self.step_fn = jax.jit(
                     make_train_step(self.model, self.optimizer, self.ctx),
                     donate_argnums=(0,))
-            with telemetry.span("trainer.init_state"):
-                self.state = init_state(self.model, self.optimizer, seed)
+        self.seed = seed
+        self._state = _UNBUILT
         self.step = 0
         self.ckpt = ckpt
         self.val_every = val_every
         self.metrics_steps: list = []
         self.metrics_vals: list = []
         self.step_seconds: list = []
+
+    @property
+    def state(self):
+        if self._state is _UNBUILT:
+            # concrete arrays even when first read inside a trace; unlike
+            # ensure_compile_time_eval, this folds no constants into
+            # model.init's own trace, so the bits are those of a plain call
+            with telemetry.span("trainer.init_state"), jax.core.eval_context():
+                self._state = init_state(self.model, self.optimizer, self.seed)
+        return self._state
+
+    @state.setter
+    def state(self, value):
+        self._state = value
 
     def run_steps(self, n: int):
         """Advance n steps; returns newly recorded (step, val_loss) points."""
@@ -105,13 +131,14 @@ class Trainer:
     def restore(self, sharding_fn=None, step=None):
         """Rehydrate from the latest checkpoint (or an explicit ``step``);
         the metric stream reloads from the manifest so the trial continues
-        the original stream exactly."""
+        the original stream exactly.  It restores into the shapes and dtypes
+        ``init_state`` would give, so a state never built is never built."""
         assert self.ckpt is not None
         with telemetry.span("trainer.restore"):
-            # drop the current state before reading: a full-width state and its
+            like = jax.eval_shape(
+                lambda: init_state(self.model, self.optimizer, self.seed))
+            # drop a built state before reading: a full-width state and its
             # restored copy do not fit one chip together
-            like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                                self.state)
             self.state = None
             self.state, step = self.ckpt.restore(like, step=step,
                                                  sharding_fn=sharding_fn)

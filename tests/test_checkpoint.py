@@ -148,3 +148,85 @@ def test_trainer_checkpoint_restart_bitwise(tmp_path):
     assert step == 10
     tr2.run_steps(5)
     assert tr2.metrics_vals[-1] == pytest.approx(loss_direct, rel=1e-5)
+
+
+# ------------------------------------------------------ the lazy trainer state
+
+
+def _mamba2_trainer(store, seed):
+    from repro.configs.base import get_config
+    from repro.launch.train import Trainer
+
+    return Trainer(get_config("mamba2-130m", reduced=True), batch=1, seq=16, seed=seed,
+                   ckpt=CheckpointManager(store, "lazy", save_interval_steps=10 ** 9))
+
+
+def _recorded(tmp_path, fn):
+    """``fn()``'s result and the program spans recorded while it ran."""
+    from repro import telemetry
+
+    telemetry.reset()
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        out = fn()
+    recs = telemetry.records()
+    telemetry.reset()
+    return out, recs
+
+
+@pytest.mark.parametrize("built_first", [False, True], ids=["unbuilt", "built"])
+@pytest.mark.parametrize("elastic", [False, True], ids=["local", "sharding_fn"])
+def test_restored_trainer_gives_back_the_saved_bits(tmp_path, built_first, elastic):
+    """A trainer restored before its state is read never builds the random
+    state, with or without a re-shard; one whose state was read first builds
+    it once.  Both hold the saved bits, which differ from their own random
+    init (another seed)."""
+    store = LocalObjectStore(str(tmp_path / "s"))
+    saver = _mamba2_trainer(store, seed=1)
+    saver.step = 5
+    saver.save(blocking=True)
+    want = jax.device_get(saver.state)
+    dev = jax.devices()[1]
+    sharding_fn = (lambda tmpl: jax.sharding.SingleDeviceSharding(dev)) if elastic else None
+
+    def resume():
+        tr = _mamba2_trainer(store, seed=0)
+        if built_first:
+            jax.block_until_ready(tr.state)
+        return tr, tr.restore(sharding_fn=sharding_fn)
+
+    (tr, step), recs = _recorded(tmp_path, resume)
+    assert step == 5 and tr.step == 5
+    got = jax.tree.leaves(tr.state)
+    assert len(got) == len(jax.tree.leaves(want))
+    for a, b in zip(jax.tree.leaves(want), got):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, np.asarray(b))
+    if elastic:
+        assert all(leaf.devices() == {dev} for leaf in got)
+    (init,) = [r for r in recs if r.name == "trainer.init"]
+    (restore,) = [r for r in recs if r.name == "trainer.restore"]
+    assert [r.name for r in recs if r.parent == init.id] == ["trainer.build"]
+    init_states = [r for r in recs if r.name == "trainer.init_state"]
+    assert len(init_states) == int(built_first)
+    assert all(r.t1 <= restore.t0 for r in init_states)
+
+
+@pytest.mark.parametrize("first_read", ["plain", "eval_shape"])
+def test_unrestored_trainer_holds_the_random_init(tmp_path, first_read):
+    """Read without a restore, a trainer holds ``init_state``'s bits; a first
+    read inside a trace still keeps concrete arrays, not tracers."""
+    from repro.launch.train import init_state
+
+    tr = _mamba2_trainer(LocalObjectStore(str(tmp_path / "s")), seed=3)
+    if first_read == "eval_shape":
+        shapes = jax.eval_shape(lambda: tr.state)
+        assert all(isinstance(s, jax.ShapeDtypeStruct) for s in jax.tree.leaves(shapes))
+    held = tr.state
+    assert all(isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer)
+               for x in jax.tree.leaves(held))
+    want = init_state(tr.model, tr.optimizer, 3)
+    assert jax.tree.structure(held) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(held)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert tr.state is held

@@ -198,10 +198,17 @@ def test_trainer_spans_are_the_programs_own(recorder, tmp_path):
     assert not names & BENCHMARK_SPANS
     inits = by_name(recs, "trainer.init")
     assert len(inits) == 2
-    for name in ("trainer.build", "trainer.init_state"):
-        assert sorted(r.parent for r in by_name(recs, name)) == sorted(r.id for r in inits)
+    # construction builds no state: each trainer.init holds trainer.build alone
+    for init in inits:
+        assert [r.name for r in recs if r.parent == init.id] == ["trainer.build"]
+    assert sorted(r.parent for r in by_name(recs, "trainer.build")) == sorted(
+        r.id for r in inits)
     (restore,) = by_name(recs, "trainer.restore")
     assert by_name(recs, "ckpt.restore")[0].parent == restore.id
-    # the first build traces and compiles the random init; the counts land there
-    first = min(by_name(recs, "trainer.init_state"), key=lambda r: r.t0)
+    # the first trainer builds its random state when save first reads it, and
+    # that build traces and compiles the random init; the counts land there.
+    # The resumed trainer builds none.
+    (first,) = by_name(recs, "trainer.init_state")
+    (save,) = by_name(recs, "ckpt.save")
+    assert first.parent is None and first.t1 <= save.t0
     assert first.counts.get("/jax/core/compile/jaxpr_trace_duration", 0) > 0

@@ -257,6 +257,38 @@ def test_pbt_exploit_resumes_from_donor_checkpoint(qwen):
                          be._host_state(be._run(donor_spec), dstep))
 
 
+@pytest.mark.parametrize("path", ["inherit", "replayer"])
+def test_assigned_trainer_never_builds_its_random_state(qwen, tmp_path, path):
+    """The inherit path and the replayer assign a state to a new Trainer
+    before anything reads it, so neither builds the random init."""
+    from repro import telemetry
+
+    be, w = qwen
+    donor = TrialSpec(w, w.hp_grid()[0], 0)
+    be.metric_at(donor, 8)                            # materialize donor run
+    run = be._run(donor)
+    child = TrialSpec(w, w.hp_grid()[1], 1, inherit=(donor.key, 8))
+    if path == "inherit":
+        be._runs.pop((child.key, child.inherit), None)  # the next _run builds it
+        want = be._host_state(be._by_key[donor.key], 8)  # the donor it inherits from
+    else:
+        run.replayer = None                           # the next replay builds one
+    telemetry.reset()
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        if path == "inherit":
+            got = be._run(child).state0
+        else:
+            got = be._host_state(run, 3)
+    recs = telemetry.records()
+    telemetry.reset()
+    names = [r.name for r in recs]
+    assert "trainer.init" in names and "trainer.init_state" not in names
+    if path == "replayer":
+        assert run.replayer is not None and run.replayer.step == 3
+        want = be._host_state(run, 3)
+    assert _leaves_equal(got, want)
+
+
 def test_trimtuner_warm_start_declares_inherit():
     from repro.tuner.policies.trimtuner import TrimTunerSearcher
 
