@@ -134,20 +134,16 @@ def embed_tokens(params, tokens, cfg, positions=None):
     return x
 
 
-def softmax_xent_sharded_vocab(logits, labels, mask=None):
-    """Cross-entropy that stays numerically safe with a model-sharded vocab.
+def softmax_xent(logits, labels, mask):
+    """Mean cross-entropy over the positions where ``mask`` is set, in float32.
 
-    logits: (B, S, V) (V possibly sharded over 'model'); labels: (B, S).
-    Returns mean loss over unmasked positions.  All reductions over V are
-    expressible as all-reduces of (B, S) scalars under SPMD.
+    logits: (B, S, V) (V possibly sharded over 'model'); labels, mask: (B, S);
+    labels at masked positions are ignored.  Both reductions over V are
+    all-reduces of (B, S) scalars under SPMD.
     """
     logits32 = logits.astype(jnp.float32)
-    m = jnp.max(logits32, axis=-1, keepdims=True)
-    shifted = logits32 - jax.lax.stop_gradient(m)
-    lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1)) + m[..., 0]
-    gold = jnp.take_along_axis(logits32, labels[..., None], axis=-1)[..., 0]
-    nll = lse - gold
-    if mask is None:
-        return jnp.mean(nll)
-    mask = mask.astype(jnp.float32)
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    lse = jax.nn.logsumexp(logits32, axis=-1)
+    gold = jnp.take_along_axis(
+        logits32, jnp.where(mask, labels, 0)[..., None], axis=-1)[..., 0]
+    m = mask.astype(jnp.float32)
+    return jnp.sum((lse - gold) * m) / jnp.maximum(jnp.sum(m), 1.0)

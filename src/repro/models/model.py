@@ -98,12 +98,13 @@ class Model:
         positions = jnp.arange(S)
         return ctx.constrain(x, "residual"), positions
 
-    def _unembed(self, params, x, ctx):
+    def _unembed(self, params, x, ctx, rule="logits"):
+        """Final norm and unembed; ``rule`` names the logits' sharding."""
         cfg = self.cfg
         x = (layers.layer_norm(x, params["ln_f"], cfg.norm_eps)
              if cfg.family == "audio" else layers.rms_norm(x, params["ln_f"], cfg.norm_eps))
         w = (params["embed"]["tok"].T if cfg.tie_embeddings else params["unembed"])
-        return ctx.constrain(x @ w, "logits")
+        return ctx.constrain(x @ w, rule)
 
     def _encode(self, params, batch, ctx):
         """Whisper encoder over stub frame embeddings."""
@@ -190,29 +191,16 @@ class Model:
     def loss(self, params, batch, ctx: Optional[ModelCtx] = None):
         """Scalar LM loss (mean xent over labels >= 0) + MoE aux.
 
-        Logits are computed with the *sequence* dim sharded over the model
-        axis (rule "logits_sp") and the vocab dim local: each device holds a
-        (B/d, S/m, V) f32 block, the xent reduces it locally, and the only
-        logits-related collective is the unembed-weight gather.  (Chunking
-        the loss with a scan looks cheaper but forces a full activation
-        gather — (B[data], S[model]) merges are inexpressible in SPMD.)"""
-        cfg = self.cfg
+        Logits take rule "logits_sp" (sequence sharded over the model axis,
+        vocab local): each device reduces its (B/d, S/m, V) block locally and
+        the only logits collective is the unembed-weight gather.  A loss
+        chunked by a scan would force a full activation gather ((B[data],
+        S[model]) merges are inexpressible in SPMD)."""
         ctx = ctx or null_ctx()
         x, aux = self._backbone(params, batch, ctx)
         labels = batch["labels"]
-        w = (params["embed"]["tok"].T if cfg.tie_embeddings else params["unembed"])
-        h = (layers.layer_norm(x, params["ln_f"], cfg.norm_eps)
-             if cfg.family == "audio"
-             else layers.rms_norm(x, params["ln_f"], cfg.norm_eps))
-        logits = ctx.constrain(h @ w, "logits_sp")
-        m = (labels >= 0).astype(jnp.float32)
-        logits32 = logits.astype(jnp.float32)
-        mx = jnp.max(logits32, axis=-1, keepdims=True)
-        lse = jnp.log(jnp.sum(jnp.exp(logits32 - jax.lax.stop_gradient(mx)),
-                              axis=-1)) + mx[..., 0]
-        gold = jnp.take_along_axis(
-            logits32, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
-        xe = jnp.sum((lse - gold) * m) / jnp.maximum(jnp.sum(m), 1.0)
+        xe = layers.softmax_xent(self._unembed(params, x, ctx, "logits_sp"),
+                                 labels, labels >= 0)
         return xe + aux, {"xent": xe, "aux": aux}
 
     def _segments(self):
