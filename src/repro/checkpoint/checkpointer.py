@@ -125,11 +125,16 @@ def restore_pytree(store, prefix: str, like, step: Optional[int] = None,
     ShapeDtypeStructs).  ``sharding_fn(leaf_template) -> Sharding`` enables
     elastic re-shard onto a new mesh.  Returns (tree, step).
 
+    A leaf saved in its template's dtype decodes in place: the host array
+    is a read-only view of the store's bytes, and only a leaf whose dtype
+    differs is converted into a copy.
+
     Spans (``repro.telemetry``): ``ckpt.restore`` around the call, with
     ``ckpt.restore.manifest`` (finding and reading it), then per leaf
     ``ckpt.restore.get``, ``ckpt.restore.decode`` and
     ``ckpt.restore.to_device``; counter ``ckpt.host_copy_bytes`` (bytes
-    copied on the host beyond those read)."""
+    copied on the host beyond those read: those of the dtype conversions,
+    0 when every leaf decodes in place)."""
     with telemetry.span("ckpt.restore"):
         with telemetry.span("ckpt.restore.manifest"):
             if step is None:
@@ -151,7 +156,7 @@ def restore_pytree(store, prefix: str, like, step: Optional[int] = None,
                 arr = np.frombuffer(data, dtype=np.dtype(meta["dtypes"][i])
                                     ).reshape(meta["shapes"][i])
                 assert list(arr.shape) == list(tmpl.shape), (i, arr.shape, tmpl.shape)
-                host = arr.astype(tmpl.dtype)
+                host = arr.astype(tmpl.dtype, copy=False)
                 if not np.may_share_memory(host, arr):
                     telemetry.count("ckpt.host_copy_bytes", host.nbytes)
             with telemetry.span("ckpt.restore.to_device"):

@@ -230,3 +230,127 @@ def test_unrestored_trainer_holds_the_random_init(tmp_path, first_read):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert tr.state is held
+
+
+# ------------------------------------------------------- decoding in place
+
+
+def _mixed_tree():
+    return {"w": jnp.arange(24, dtype=jnp.float32).reshape(4, 6) / 7,
+            "b": (jnp.arange(5, dtype=jnp.float32) / 3).astype(jnp.bfloat16),
+            "step": jnp.array(41, jnp.int32),
+            "ids": jnp.arange(6, dtype=jnp.int32).reshape(2, 3) - 2}
+
+
+def _bits(x):
+    return np.asarray(x).reshape(-1).view(np.uint8)
+
+
+def _sharding_fn(elastic):
+    if not elastic:
+        return None
+    dev = jax.devices()[1]
+    return lambda tmpl: jax.sharding.SingleDeviceSharding(dev)
+
+
+def _decode_copies(recs):
+    return [r.counts.get("ckpt.host_copy_bytes")
+            for r in recs if r.name == "ckpt.restore.decode"]
+
+
+@pytest.mark.parametrize("elastic", [False, True], ids=["asarray", "device_put"])
+def test_matching_dtypes_decode_in_place(store, tmp_path, elastic):
+    """Float, bfloat16 and int leaves restored into templates of their saved
+    dtypes come back bit for bit, and no decode copies a byte."""
+    t = _mixed_tree()
+    save_pytree(store, "ckpt", 4, t)
+    like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
+    (out, step), recs = _recorded(
+        tmp_path, lambda: restore_pytree(store, "ckpt", like, sharding_fn=_sharding_fn(elastic)))
+    assert step == 4
+    for a, b in zip(jax.tree.leaves(t), jax.tree.leaves(out)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+    if elastic:
+        assert all(leaf.devices() == {jax.devices()[1]} for leaf in jax.tree.leaves(out))
+    assert _decode_copies(recs) == [None] * len(jax.tree.leaves(t))
+
+
+@pytest.mark.parametrize("elastic", [False, True], ids=["asarray", "device_put"])
+def test_other_dtype_template_converts_and_counts(store, tmp_path, elastic):
+    """A leaf restored into a template of another dtype is numpy's ``astype``
+    of the saved one, and its decode counts exactly the converted bytes; the
+    leaves of matching dtype in the same tree count none."""
+    t = _mixed_tree()
+    save_pytree(store, "ckpt", 2, t)
+    like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
+    like["w"] = jax.ShapeDtypeStruct(t["w"].shape, jnp.bfloat16)
+    like["b"] = jax.ShapeDtypeStruct(t["b"].shape, jnp.float32)
+    (out, _), recs = _recorded(
+        tmp_path, lambda: restore_pytree(store, "ckpt", like, sharding_fn=_sharding_fn(elastic)))
+    want = {k: np.asarray(t[k]).astype(like[k].dtype) for k in t}
+    for k in t:
+        assert out[k].dtype == like[k].dtype
+        np.testing.assert_array_equal(_bits(out[k]), _bits(want[k]))
+    leaves_want = jax.tree.leaves(want)   # the template's flattening order
+    converted = [k in ("w", "b") for k in sorted(t)]
+    assert _decode_copies(recs) == [leaf.nbytes if c else None
+                                    for leaf, c in zip(leaves_want, converted)]
+
+
+class _AlignedStore:
+    """A host-memory store whose leaves are read-only, 64-byte aligned
+    buffers: the alignment at which the CPU backend adopts a host array
+    without a copy, so the restored state may alias the stored snapshot."""
+
+    def __init__(self):
+        self.blobs = {}
+
+    def put(self, key, data):
+        if key.endswith(".npy"):
+            raw = np.empty(len(data) + 64, np.uint8)
+            off = -raw.ctypes.data % 64
+            buf = raw[off:off + len(data)]
+            buf[:] = np.frombuffer(data, np.uint8)
+            buf.flags.writeable = False
+            data = memoryview(buf)
+        self.blobs[key] = data
+
+    def get(self, key):
+        return self.blobs[key]
+
+    def delete(self, key):
+        self.blobs.pop(key, None)
+
+    def list(self, prefix=""):
+        return sorted(k for k in self.blobs if k.startswith(prefix))
+
+
+@pytest.mark.parametrize("kind", ["local", "aligned"])
+def test_donated_step_leaves_the_stored_snapshot_intact(tmp_path, kind):
+    """A restored state may be a view of the store's bytes on the host; the
+    train step donates its state, and that must never write into a stored
+    snapshot.  After one donated step, a second restore of the same step
+    gives the first one's bits, and the stored leaves are unchanged."""
+    store = LocalObjectStore(str(tmp_path / "s")) if kind == "local" else _AlignedStore()
+    saver = _mamba2_trainer(store, seed=1)
+    saver.step = 3
+    saver.save(blocking=True)
+    leaf_keys = [k for k in store.list("lazy/") if k.endswith(".npy")]
+    stored = {k: bytes(store.get(k)) for k in leaf_keys}
+
+    tr = _mamba2_trainer(store, seed=0)
+    assert tr.restore() == 3
+    first = [np.array(x) for x in jax.tree.leaves(tr.state)]
+    tr.run_steps(1)
+    assert tr.step == 4
+    jax.block_until_ready(tr.state)
+
+    again = _mamba2_trainer(store, seed=0)
+    assert again.restore(step=3) == 3
+    second = jax.tree.leaves(again.state)
+    assert len(second) == len(first)
+    for a, b in zip(first, second):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+    assert {k: bytes(store.get(k)) for k in leaf_keys} == stored

@@ -172,11 +172,23 @@ def test_checkpoint_save_and_restore_spans(recorder, tmp_path, blocking):
     def total(key):
         return sum(r.counts.get(key, 0) for r in recs)
 
-    # the decode's astype copy: every leaf's bytes, on its own decode span
+    # every saved dtype is the template's: each leaf decodes in place
     assert [r.counts.get("ckpt.host_copy_bytes") for r in by_name(recs, "ckpt.restore.decode")
-            ] == [np.asarray(leaf).nbytes for leaf in jax.tree.leaves(tree)]
-    assert total("ckpt.host_copy_bytes") == tree_bytes(tree)
+            ] == [None] * n
+    assert total("ckpt.host_copy_bytes") == 0
     assert not hasattr(mgr, "save_seconds") and not hasattr(mgr, "saves")
+
+    # a template of another dtype for one leaf: that leaf's conversion copy,
+    # its converted bytes, on its own decode span, and no other
+    telemetry.reset()
+    like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+    like["w"] = jax.ShapeDtypeStruct(tree["w"].shape, jnp.bfloat16)
+    mgr.restore(like)
+    recs = telemetry.records()
+    assert [r.counts.get("ckpt.host_copy_bytes") for r in by_name(recs, "ckpt.restore.decode")
+            ] == [tree_bytes({"w": like["w"]}) if leaf is like["w"] else None
+                  for leaf in jax.tree.leaves(like)]
+    assert total("ckpt.host_copy_bytes") == tree_bytes({"w": like["w"]})
 
 
 def test_trainer_spans_are_the_programs_own(recorder, tmp_path):
