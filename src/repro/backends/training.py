@@ -56,10 +56,10 @@ from repro.core.trial import TrialSpec, Workload
 from repro.data.pipeline import SyntheticLMDataset
 from repro.launch.hlo_cost import module_cost
 from repro.launch.roofline import HBM_BW, LINK_BW, PEAK_FLOPS
-from repro.launch.train import Trainer, init_state, make_train_step
+from repro.launch.train import (Trainer, make_train_step, state_template,
+                                trial_optimizer)
 from repro.models.context import null_ctx
 from repro.models.model import Model
-from repro.optim import adamw
 from repro.optim.schedules import exponential_decay_schedule
 
 
@@ -97,14 +97,6 @@ class TrainingBinding:
                     val_every=val_every)
 
 
-def _state_template(arch: str, reduced: bool = True, seed: int = 0):
-    """Abstract (shape/dtype) full-training-state pytree — no compute."""
-    cfg = get_config(arch, reduced=reduced)
-    model = Model(cfg)
-    optimizer = adamw(3e-3, keep_master=(cfg.opt_precision == "fp32"))
-    return jax.eval_shape(lambda: init_state(model, optimizer, seed))
-
-
 def training_workload(arch: str, max_steps: int = 48, val_every: int = 4,
                       s0: float = 150.0, batch: int = 4, seq: int = 32,
                       ) -> Workload:
@@ -116,7 +108,8 @@ def training_workload(arch: str, max_steps: int = 48, val_every: int = 4,
     ``model_bytes`` is measured from the abstract state pytree (params +
     AdamW moments + fp32 master copies), not a table entry.
     """
-    bytes_ = float(tree_bytes(_state_template(arch)))
+    cfg = get_config(arch, reduced=True)
+    bytes_ = float(tree_bytes(state_template(Model(cfg), trial_optimizer(cfg, 3e-3))))
     hp_space = (("lr", (3e-3, 1e-3)), ("dr", (1.0, 0.5)),
                 ("bs", (batch, max(1, batch // 2))), ("ds", (max_steps // 3,)))
     return Workload(f"train-{arch}", hp_space, max_trial_steps=max_steps,
@@ -151,7 +144,7 @@ def _step_cost(binding: TrainingBinding, bs: int) -> tuple:
         return hit
     cfg = get_config(binding.arch, reduced=binding.reduced)
     model = Model(cfg)
-    optimizer = adamw(3e-3, keep_master=(cfg.opt_precision == "fp32"))
+    optimizer = trial_optimizer(cfg, 3e-3)
     ctx = null_ctx(attn_chunk=min(512, binding.seq), remat="none")
     # module_cost reads the CPU backend's HLO (a TPU compile hides its dots
     # in fusions it does not count): compile for the CPU wherever this runs,
@@ -159,8 +152,7 @@ def _step_cost(binding: TrainingBinding, bs: int) -> tuple:
     cpu = jax.sharding.SingleDeviceSharding(jax.devices("cpu")[0])
     on_cpu = lambda t: jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=cpu), t)
-    state_shapes = on_cpu(jax.eval_shape(
-        lambda: init_state(model, optimizer, binding.seed)))
+    state_shapes = on_cpu(state_template(model, optimizer, binding.seed))
     batch_shapes = on_cpu(SyntheticLMDataset(
         cfg, bs, binding.seq, seed=binding.seed).get_batch(0))
     step = make_train_step(model, optimizer, ctx)
@@ -238,7 +230,7 @@ class TrainingTrialBackend(TrialBackend):
                  bandwidth_bps: float = 134.22e6, latency_s: float = 0.05,
                  ref_chips: int = 8,
                  bindings: Optional[Dict[str, TrainingBinding]] = None,
-                 sharding_fn=None):
+                 sharding=None):
         self.pool = list(pool or DEFAULT_POOL)
         self.ref_chips = ref_chips
         root = root or tempfile.mkdtemp(prefix="spottune-training-")
@@ -248,7 +240,7 @@ class TrainingTrialBackend(TrialBackend):
         self.bindings = dict(TRAINING_BINDINGS)
         if bindings:
             self.bindings.update(bindings)
-        self.sharding_fn = sharding_fn
+        self.sharding = sharding        # one Sharding for every restored leaf
         self._runs: Dict[tuple, _Run] = {}      # (trial.key, inherit) -> run
         self._by_key: Dict[str, _Run] = {}      # trial.key -> latest run
         # observability for tests/benchmarks
@@ -344,8 +336,8 @@ class TrainingTrialBackend(TrialBackend):
                                   else run.state0)
             rp.step = cached
         else:
-            rp.state, got = restore_pytree(self.store, run.prefix,
-                                           rp.state, step=snap)
+            rp.state, got, _ = restore_pytree(self.store, run.prefix,
+                                              rp.state, step=snap)
             rp.step = got
         if rp.step < step:
             rp.run_steps(step - rp.step)
@@ -438,9 +430,10 @@ class TrainingTrialBackend(TrialBackend):
         # the host copy's shapes and dtypes: no device copy to read them from
         like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                             run.state0)
-        state, got = restore_pytree(self.store, run.prefix, like,
-                                    step=snaps[-1],
-                                    sharding_fn=self.sharding_fn)
+        shardings = (None if self.sharding is None
+                     else jax.tree.map(lambda _: self.sharding, like))
+        state, got, _ = restore_pytree(self.store, run.prefix, like,
+                                       step=snaps[-1], shardings=shardings)
         self.restores += 1
         self.last_restore = (trial.key, got, _to_host(state))
         return None

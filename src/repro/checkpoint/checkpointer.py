@@ -7,9 +7,11 @@ naturally):
     <prefix>/step_<N>/MANIFEST.json     # written LAST -> atomicity marker
 
 A checkpoint is valid iff its manifest exists (readers ignore torn writes).
-``restore_pytree`` can re-shard onto a *different* mesh than the writer's —
-this is the elastic path used when a revoked trial is re-deployed on another
-slice type (SpotTune Algorithm 1 lines 24-26).
+The layout is this module's alone: ``restore_pytree`` returns the tree, its
+step and the manifest's ``extra`` dict, so no caller reads a manifest.  Given
+a pytree of shardings it places each leaf onto a *different* mesh than the
+writer's — the elastic path used when a revoked trial is re-deployed on
+another slice type (SpotTune Algorithm 1 lines 24-26).
 
 The 2-minute-revocation-notice budget: ``CheckpointManager.fits_deadline``
 predicts the transfer time from the store's bandwidth model, reproducing the
@@ -18,12 +20,12 @@ paper's "max model size = speed x 120 s" bound (§IV-F).
 
 from __future__ import annotations
 
-import io
 import json
 import threading
-from typing import Any, Callable, Optional
+from typing import Optional
 
 import jax
+import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
 import numpy as np
 
 from repro import telemetry
@@ -120,10 +122,15 @@ def latest_step(store, prefix: str) -> Optional[int]:
 
 
 def restore_pytree(store, prefix: str, like, step: Optional[int] = None,
-                   sharding_fn: Optional[Callable[[Any], Any]] = None):
+                   shardings=None):
     """Restore into the structure of ``like`` (a pytree of arrays or
-    ShapeDtypeStructs).  ``sharding_fn(leaf_template) -> Sharding`` enables
-    elastic re-shard onto a new mesh.  Returns (tree, step).
+    ShapeDtypeStructs).  Returns ``(tree, step, extra)``, ``extra`` being
+    the manifest's ``extra`` (the save's ``extra_meta``; ``{}`` when none).
+
+    With ``shardings=None`` each leaf goes to the default device
+    (``jnp.asarray``); otherwise ``shardings`` is a pytree of ``like``'s
+    structure and each leaf is ``device_put`` onto its own sharding, the
+    elastic re-shard onto a new mesh.
 
     A leaf saved in its template's dtype decodes in place: the host array
     is a read-only view of the store's bytes, and only a leaf whose dtype
@@ -146,10 +153,9 @@ def restore_pytree(store, prefix: str, like, step: Optional[int] = None,
         leaves_like, treedef = jax.tree.flatten(like)
         assert meta["n_leaves"] == len(leaves_like), (
             f"checkpoint has {meta['n_leaves']} leaves, template has {len(leaves_like)}")
+        leaves_sh = None if shardings is None else treedef.flatten_up_to(shardings)
         out = []
         for i, tmpl in enumerate(leaves_like):
-            import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
-
             with telemetry.span("ckpt.restore.get"):
                 data = store.get(f"{base}/leaf_{i:05d}.npy")
             with telemetry.span("ckpt.restore.decode"):
@@ -160,11 +166,11 @@ def restore_pytree(store, prefix: str, like, step: Optional[int] = None,
                 if not np.may_share_memory(host, arr):
                     telemetry.count("ckpt.host_copy_bytes", host.nbytes)
             with telemetry.span("ckpt.restore.to_device"):
-                if sharding_fn is not None:
-                    out.append(jax.device_put(host, sharding_fn(tmpl)))
+                if leaves_sh is not None:
+                    out.append(jax.device_put(host, leaves_sh[i]))
                 else:
                     out.append(jax.numpy.asarray(host))
-    return jax.tree.unflatten(treedef, out), step
+    return jax.tree.unflatten(treedef, out), step, meta.get("extra", {})
 
 
 class CheckpointManager:
@@ -177,6 +183,7 @@ class CheckpointManager:
         self.save_interval_steps = save_interval_steps
         self.keep_n = keep_n
         self._pending = None
+        self.restored_extra: dict = {}  # the manifest's ``extra`` of the last restore
 
     def should_save(self, step: int) -> bool:
         return step > 0 and step % self.save_interval_steps == 0
@@ -201,15 +208,16 @@ class CheckpointManager:
             return self.store.transfer_time(tree_bytes(tree)) <= deadline_s
         return True
 
-    def restore_latest(self, like, sharding_fn=None):
-        return restore_pytree(self.store, self.prefix, like, sharding_fn=sharding_fn)
-
-    def restore(self, like, step: Optional[int] = None, sharding_fn=None):
-        """Restore a specific checkpoint step (None = latest) — the
-        re-deploy path when a revoked trial must resume from the snapshot
-        that actually fit the notice deadline, not the newest one."""
-        return restore_pytree(self.store, self.prefix, like, step=step,
-                              sharding_fn=sharding_fn)
+    def restore(self, like, step: Optional[int] = None, shardings=None):
+        """``restore_pytree`` of a specific checkpoint step (None = latest) —
+        the re-deploy path when a revoked trial must resume from the snapshot
+        that actually fit the notice deadline, not the newest one.  Returns
+        ``(tree, step)`` and keeps the manifest's ``extra`` in
+        ``restored_extra``: the benchmark's fault tests wrap this method and
+        unpack two values."""
+        tree, step, self.restored_extra = restore_pytree(
+            self.store, self.prefix, like, step=step, shardings=shardings)
+        return tree, step
 
     def _gc(self):
         all_steps = steps(self.store, self.prefix)

@@ -27,7 +27,6 @@ import time
 import traceback
 
 import jax
-import numpy as np
 
 from repro.configs.base import ARCH_IDS, SHAPES, get_config, shape_applicable
 from repro.models import inputs as inputs_lib
@@ -35,8 +34,7 @@ from repro.models.model import Model, model_flops, matmul_param_count, count_par
 from repro.launch.hlo_cost import module_cost
 from repro.launch.mesh import make_production_mesh, make_small_mesh
 from repro.launch.sharding import Policy
-from repro.launch.train import make_train_step
-from repro.optim import adamw
+from repro.launch.train import make_train_step, trial_optimizer
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "artifacts", "dryrun")
 
@@ -155,7 +153,7 @@ def lower_cell(arch: str, shape_name: str, mesh, verbose: bool = True):
     t0 = time.monotonic()
     if shape.kind == "train":
         ctx = policy.ctx()
-        opt = adamw(3e-4, keep_master=(cfg.opt_precision == "fp32"))
+        opt = trial_optimizer(cfg, 3e-4)
         opt_shapes = jax.eval_shape(opt.init, params_shapes)
         opt_sh = policy.opt_state_shardings(opt_shapes, param_sh)
         batch_shapes = inputs_lib.train_batch_shapes(

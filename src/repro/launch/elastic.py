@@ -65,10 +65,12 @@ def reshard_state(state, shardings):
 class ElasticTrial:
     """Checkpoint-backed migration: save on slice A, restore sharded on B.
 
-    The restore path never materializes more than one leaf unsharded on a
-    single host — each leaf is loaded from the store and device_put straight
-    to its target sharding (the multi-host generalization reads per-shard
-    byte ranges; the store layout is already one object per leaf)."""
+    ``restore_onto`` hands ``restore_pytree`` the target mesh's pytree of
+    shardings and returns ``(state, step)``.  The restore never materializes
+    more than one leaf unsharded on a single host — each leaf is loaded from
+    the store and device_put straight to its target sharding (the multi-host
+    generalization reads per-shard byte ranges; the store layout is already
+    one object per leaf)."""
 
     def __init__(self, cfg, store, prefix: str, kind: str = "train"):
         self.cfg = cfg
@@ -84,17 +86,7 @@ class ElasticTrial:
                      global_batch: Optional[int] = None):
         shardings = state_shardings(self.cfg, mesh, state_shapes, self.kind,
                                     global_batch)
-        # restore leaf-by-leaf with per-leaf shardings (restore_pytree walks
-        # leaves in template order)
-        leaves_sh = jax.tree.leaves(shardings)
-        counter = {"i": 0}
-
-        def sharding_fn(tmpl):
-            s = leaves_sh[counter["i"]]
-            counter["i"] += 1
-            return s
-
-        state, got_step = restore_pytree(self.store, self.prefix,
-                                         state_shapes, step=step,
-                                         sharding_fn=sharding_fn)
+        state, got_step, _ = restore_pytree(self.store, self.prefix,
+                                            state_shapes, step=step,
+                                            shardings=shardings)
         return state, got_step

@@ -48,6 +48,17 @@ def init_state(model: Model, optimizer: Optimizer, seed: int = 0):
     return {"params": params, "opt": optimizer.init(params)}
 
 
+def trial_optimizer(cfg, lr) -> Optimizer:
+    """A trial's AdamW: an fp32 master copy of the params iff the config
+    asks for fp32 optimizer precision."""
+    return adamw(lr, keep_master=(cfg.opt_precision == "fp32"))
+
+
+def state_template(model: Model, optimizer: Optimizer, seed: int = 0):
+    """The shapes and dtypes of ``init_state``, computing nothing."""
+    return jax.eval_shape(lambda: init_state(model, optimizer, seed))
+
+
 _UNBUILT = object()     # ``Trainer._state`` before anything reads or assigns it
 
 
@@ -73,8 +84,8 @@ class Trainer:
             with telemetry.span("trainer.build"):
                 self.cfg = cfg
                 self.model = Model(cfg)
-                self.optimizer = adamw(lr_schedule if lr_schedule is not None else lr,
-                                       keep_master=(cfg.opt_precision == "fp32"))
+                self.optimizer = trial_optimizer(
+                    cfg, lr_schedule if lr_schedule is not None else lr)
                 self.ctx = ctx or null_ctx(attn_chunk=min(512, seq), remat="none")
                 self.data = SyntheticLMDataset(cfg, batch, seq, seed=seed)
                 self.step_fn = jax.jit(
@@ -128,28 +139,22 @@ class Trainer:
                 "metrics_vals": self.metrics_vals}
         self.ckpt.save(self.step, self.state, blocking=blocking, extra_meta=meta)
 
-    def restore(self, sharding_fn=None, step=None):
-        """Rehydrate from the latest checkpoint (or an explicit ``step``);
-        the metric stream reloads from the manifest so the trial continues
-        the original stream exactly.  It restores into the shapes and dtypes
-        ``init_state`` would give, so a state never built is never built."""
+    def restore(self, step=None, shardings=None):
+        """Rehydrate from the latest checkpoint (or an explicit ``step``) and
+        return its step; ``shardings`` (a pytree of the state's structure)
+        places each leaf, as in ``restore_pytree``.  The metric stream
+        reloads from the checkpoint's manifest, so the trial continues the
+        original stream exactly.  It restores into ``state_template``, so a
+        state never built is never built."""
         assert self.ckpt is not None
         with telemetry.span("trainer.restore"):
-            like = jax.eval_shape(
-                lambda: init_state(self.model, self.optimizer, self.seed))
+            like = state_template(self.model, self.optimizer, self.seed)
             # drop a built state before reading: a full-width state and its
             # restored copy do not fit one chip together
             self.state = None
-            self.state, step = self.ckpt.restore(like, step=step,
-                                                 sharding_fn=sharding_fn)
+            self.state, step = self.ckpt.restore(like, step=step, shardings=shardings)
             self.step = step
-            import json
-
-            from repro.checkpoint.checkpointer import MANIFEST
-
-            base = f"{self.ckpt.prefix}/step_{step:08d}"
-            meta = json.loads(self.ckpt.store.get(f"{base}/{MANIFEST}").decode())
-            extra = meta.get("extra", {})
+            extra = self.ckpt.restored_extra
             self.metrics_steps = list(extra.get("metrics_steps", []))
             self.metrics_vals = list(extra.get("metrics_vals", []))
         return step

@@ -27,8 +27,8 @@ def tree():
 def test_roundtrip(store):
     t = tree()
     save_pytree(store, "ckpt", 10, t)
-    out, step = restore_pytree(store, "ckpt", t)
-    assert step == 10
+    out, step, extra = restore_pytree(store, "ckpt", t)
+    assert step == 10 and extra == {}
     np.testing.assert_array_equal(np.asarray(out["a"]), np.asarray(t["a"]))
     np.testing.assert_array_equal(np.asarray(out["b"]["c"], np.float32),
                                   np.asarray(t["b"]["c"], np.float32))
@@ -70,17 +70,36 @@ def test_deadline_model(tmp_path):
 
 
 def test_elastic_restore_resharding_hook(store):
-    """sharding_fn receives each template leaf -> device placement hook."""
+    """A pytree of shardings of the template's structure places each leaf:
+    the device placement hook."""
     t = tree()
     save_pytree(store, "ckpt", 1, t)
-    calls = []
+    sh = jax.sharding.SingleDeviceSharding(jax.devices()[1])
+    out, _, _ = restore_pytree(store, "ckpt", t,
+                               shardings=jax.tree.map(lambda _: sh, t))
+    assert [leaf.sharding for leaf in jax.tree.leaves(out)] == [sh, sh]
 
-    def shard_fn(leaf):
-        calls.append(leaf.shape)
-        return jax.devices()[0]
 
-    out, _ = restore_pytree(store, "ckpt", t, sharding_fn=shard_fn)
-    assert len(calls) == 2
+def test_restore_places_each_leaf_by_position(store):
+    """Leaf ``i`` of the shardings pytree places leaf ``i`` of the state, with
+    more leaves than devices so the placement wraps round them."""
+    devs = jax.devices()
+    assert len(devs) == 8
+    t = {f"l{i:02d}": jnp.arange(i + 1, dtype=jnp.float32) * (i + 0.5) for i in range(11)}
+    t["l03"] = t["l03"].astype(jnp.bfloat16)
+    t["l07"] = jnp.arange(3, dtype=jnp.int32) - 2
+    save_pytree(store, "ckpt", 6, t, extra_meta={"note": "pos"})
+    leaves, treedef = jax.tree.flatten(t)
+    shardings = jax.tree.unflatten(treedef, [
+        jax.sharding.SingleDeviceSharding(devs[i % 8]) for i in range(len(leaves))])
+    out, step, extra = restore_pytree(store, "ckpt", t, shardings=shardings)
+    assert step == 6 and extra == {"note": "pos"}
+    got = jax.tree.leaves(out)
+    assert len(got) == len(leaves)
+    for i, (a, b) in enumerate(zip(leaves, got)):
+        assert b.sharding == jax.sharding.SingleDeviceSharding(devs[i % 8])
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(_bits(a), _bits(b))
 
 
 def test_manager_restore_specific_step(store):
@@ -89,13 +108,13 @@ def test_manager_restore_specific_step(store):
     mgr = CheckpointManager(store, "run2", save_interval_steps=10, keep_n=3)
     for s in (10, 20, 30):
         t = {"a": jnp.full((4,), float(s), jnp.float32)}
-        mgr.save(s, t, blocking=True)
+        mgr.save(s, t, blocking=True, extra_meta={"saved_at": s})
     like = {"a": jnp.zeros((4,), jnp.float32)}
     out, got = mgr.restore(like, step=20)
-    assert got == 20
+    assert got == 20 and mgr.restored_extra == {"saved_at": 20}
     np.testing.assert_array_equal(np.asarray(out["a"]), np.full((4,), 20.0))
     out, got = mgr.restore(like)              # step=None -> latest
-    assert got == 30
+    assert got == 30 and mgr.restored_extra == {"saved_at": 30}
 
 
 def test_snapshot_restore_cross_mesh_optimizer_state(tmp_path):
@@ -103,7 +122,7 @@ def test_snapshot_restore_cross_mesh_optimizer_state(tmp_path):
     through save/restore onto a *different* device than the writer's — the
     elastic re-shard path of a revoked trial re-deployed on another slice."""
     from repro.configs.base import get_config
-    from repro.launch.train import Trainer
+    from repro.launch.train import Trainer, state_template
 
     cfg = get_config("qwen1.5-0.5b", reduced=True)
     store = LocalObjectStore(str(tmp_path / "s3m"))
@@ -116,8 +135,9 @@ def test_snapshot_restore_cross_mesh_optimizer_state(tmp_path):
     dev = jax.devices()[1]
     tr2 = Trainer(cfg, batch=2, seq=16, seed=0,
                   ckpt=CheckpointManager(store, "trialX", 10 ** 9), val_every=5)
-    step = tr2.restore(
-        sharding_fn=lambda tmpl: jax.sharding.SingleDeviceSharding(dev))
+    sh = jax.sharding.SingleDeviceSharding(dev)
+    step = tr2.restore(shardings=jax.tree.map(
+        lambda _: sh, state_template(tr2.model, tr2.optimizer)))
     assert step == 7
     got = jax.tree.leaves(tr2.state)
     assert all(leaf.devices() == {dev} for leaf in got)
@@ -174,7 +194,7 @@ def _recorded(tmp_path, fn):
 
 
 @pytest.mark.parametrize("built_first", [False, True], ids=["unbuilt", "built"])
-@pytest.mark.parametrize("elastic", [False, True], ids=["local", "sharding_fn"])
+@pytest.mark.parametrize("elastic", [False, True], ids=["local", "shardings"])
 def test_restored_trainer_gives_back_the_saved_bits(tmp_path, built_first, elastic):
     """A trainer restored before its state is read never builds the random
     state, with or without a re-shard; one whose state was read first builds
@@ -186,13 +206,15 @@ def test_restored_trainer_gives_back_the_saved_bits(tmp_path, built_first, elast
     saver.save(blocking=True)
     want = jax.device_get(saver.state)
     dev = jax.devices()[1]
-    sharding_fn = (lambda tmpl: jax.sharding.SingleDeviceSharding(dev)) if elastic else None
 
     def resume():
+        from repro.launch.train import state_template
+
         tr = _mamba2_trainer(store, seed=0)
         if built_first:
             jax.block_until_ready(tr.state)
-        return tr, tr.restore(sharding_fn=sharding_fn)
+        return tr, tr.restore(shardings=_shardings(
+            elastic, state_template(tr.model, tr.optimizer)))
 
     (tr, step), recs = _recorded(tmp_path, resume)
     assert step == 5 and tr.step == 5
@@ -246,11 +268,12 @@ def _bits(x):
     return np.asarray(x).reshape(-1).view(np.uint8)
 
 
-def _sharding_fn(elastic):
+def _shardings(elastic, like):
+    """None, or every leaf of ``like`` onto the second device."""
     if not elastic:
         return None
-    dev = jax.devices()[1]
-    return lambda tmpl: jax.sharding.SingleDeviceSharding(dev)
+    sh = jax.sharding.SingleDeviceSharding(jax.devices()[1])
+    return jax.tree.map(lambda _: sh, like)
 
 
 def _decode_copies(recs):
@@ -265,8 +288,8 @@ def test_matching_dtypes_decode_in_place(store, tmp_path, elastic):
     t = _mixed_tree()
     save_pytree(store, "ckpt", 4, t)
     like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
-    (out, step), recs = _recorded(
-        tmp_path, lambda: restore_pytree(store, "ckpt", like, sharding_fn=_sharding_fn(elastic)))
+    (out, step, _), recs = _recorded(
+        tmp_path, lambda: restore_pytree(store, "ckpt", like, shardings=_shardings(elastic, like)))
     assert step == 4
     for a, b in zip(jax.tree.leaves(t), jax.tree.leaves(out)):
         assert a.dtype == b.dtype and a.shape == b.shape
@@ -286,8 +309,8 @@ def test_other_dtype_template_converts_and_counts(store, tmp_path, elastic):
     like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
     like["w"] = jax.ShapeDtypeStruct(t["w"].shape, jnp.bfloat16)
     like["b"] = jax.ShapeDtypeStruct(t["b"].shape, jnp.float32)
-    (out, _), recs = _recorded(
-        tmp_path, lambda: restore_pytree(store, "ckpt", like, sharding_fn=_sharding_fn(elastic)))
+    (out, _, _), recs = _recorded(
+        tmp_path, lambda: restore_pytree(store, "ckpt", like, shardings=_shardings(elastic, like)))
     want = {k: np.asarray(t[k]).astype(like[k].dtype) for k in t}
     for k in t:
         assert out[k].dtype == like[k].dtype
@@ -354,3 +377,31 @@ def test_donated_step_leaves_the_stored_snapshot_intact(tmp_path, kind):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(_bits(a), _bits(b))
     assert {k: bytes(store.get(k)) for k in leaf_keys} == stored
+
+
+class _ManifestCountingStore(LocalObjectStore):
+    """A local store that counts the gets of checkpoint manifests."""
+
+    manifest_gets = 0
+
+    def get(self, key):
+        if key.endswith(MANIFEST):
+            self.manifest_gets += 1
+        return super().get(key)
+
+
+def test_trainer_restore_reads_the_manifest_once(tmp_path):
+    """A trainer's restore gets the checkpoint's manifest once, and takes its
+    metric stream from that one read."""
+    store = _ManifestCountingStore(str(tmp_path / "s"))
+    saver = _mamba2_trainer(store, seed=1)
+    saver.step = 20
+    saver.metrics_steps, saver.metrics_vals = [10, 20], [2.5, 2.25]
+    saver.save(blocking=True)
+    assert store.manifest_gets == 0
+    tr = _mamba2_trainer(store, seed=0)
+    assert tr.restore() == 20
+    assert store.manifest_gets == 1
+    assert (tr.metrics_steps, tr.metrics_vals) == ([10, 20], [2.5, 2.25])
+    assert tr.restore(step=20) == 20
+    assert store.manifest_gets == 2

@@ -13,11 +13,14 @@ import pytest
 
 from repro.backends import BACKENDS, TrialBackend, make_backend
 from repro.backends.training import (TRAINING_BINDINGS, TRAINING_WORKLOADS,
-                                     TrainingBinding, TrainingTrialBackend)
+                                     TrainingBinding, TrainingTrialBackend,
+                                     training_workload)
 from repro.checkpoint import CheckpointManager
+from repro.checkpoint.checkpointer import tree_bytes
+from repro.configs.base import get_config
 from repro.core.market import DEFAULT_POOL
 from repro.core.trial import SimTrialBackend, TrialSpec
-from repro.launch.train import Trainer
+from repro.launch.train import Trainer, state_template
 from repro.sweep.runner import SweepRunner
 from repro.sweep.spec import ScenarioSpec
 from repro.tuner.policies.pbt import PBTScheduler, PBTSearcher
@@ -119,14 +122,13 @@ def test_mamba2_multi_seed_losses_finite(data_seed):
     training must stay finite on every data seed."""
     from repro.configs.base import get_config
     from repro.data.pipeline import SyntheticLMDataset
-    from repro.launch.train import init_state, make_train_step
+    from repro.launch.train import init_state, make_train_step, trial_optimizer
     from repro.models.context import null_ctx
     from repro.models.model import Model
-    from repro.optim.optimizers import adamw
 
     cfg = get_config("mamba2-130m", reduced=True)
     model = Model(cfg)
-    opt = adamw(3e-3, keep_master=(cfg.opt_precision == "fp32"))
+    opt = trial_optimizer(cfg, 3e-3)
     state = init_state(model, opt, 0)
     ds = SyntheticLMDataset(cfg, 4, 32, seed=data_seed)
     step = jax.jit(make_train_step(model, opt, null_ctx(attn_chunk=32,
@@ -144,14 +146,26 @@ def test_mamba2_binding_uses_default_data_seed():
     assert TRAINING_BINDINGS[TRAINING_WORKLOADS["mamba2-130m"].name].seed == 0
 
 
+@pytest.mark.parametrize("arch", ["mamba2-130m", "qwen1.5-0.5b"])
+def test_state_template_is_the_trainers_state(arch):
+    """The one template a resumed trainer restores into, and the backend
+    prices, is the abstract form of the state a trainer builds."""
+    tr = Trainer(get_config(arch, reduced=True), batch=1, seq=16, seed=0)
+    template = state_template(tr.model, tr.optimizer)
+    built = jax.eval_shape(lambda: tr.state)
+    assert jax.tree.structure(template) == jax.tree.structure(built)
+    assert jax.tree.leaves(template) == jax.tree.leaves(built)
+    assert training_workload(arch).model_bytes == tree_bytes(template)
+
+
 # ------------------------------------------------------- checkpoint lifecycle
 
 
 def test_snapshot_restore_cross_mesh_bit_identical(qwen):
     _, w = qwen
     dev = jax.devices()[1]
-    be = TrainingTrialBackend(
-        sharding_fn=lambda tmpl: jax.sharding.SingleDeviceSharding(dev))
+    sh = jax.sharding.SingleDeviceSharding(dev)
+    be = TrainingTrialBackend(sharding=sh)
     t = TrialSpec(w, w.hp_grid()[0], 0)
     assert be.snapshot(t, 8, deadline_s=120.0) == 8.0
     be.restore(t, 8)
@@ -163,10 +177,9 @@ def test_snapshot_restore_cross_mesh_bit_identical(qwen):
     assert _leaves_equal(restored, be._host_state(run, 8))
     like = jax.tree.map(jax.numpy.asarray, run.state0)
     from repro.checkpoint.checkpointer import restore_pytree
-    tree, got = restore_pytree(
-        be.store, run.prefix, like, step=8,
-        sharding_fn=lambda tmpl: jax.sharding.SingleDeviceSharding(dev))
-    assert got == 8
+    tree, got, extra = restore_pytree(
+        be.store, run.prefix, like, step=8, shardings=jax.tree.map(lambda _: sh, like))
+    assert got == 8 and extra["metrics_steps"] == [4, 8]
     assert all(leaf.devices() == {dev} for leaf in jax.tree.leaves(tree))
 
 
