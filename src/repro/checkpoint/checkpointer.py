@@ -45,27 +45,50 @@ def tree_bytes(tree) -> int:
                for l in jax.tree.leaves(tree))
 
 
+def _to_host(leaves):
+    """Each leaf as a host array, in order, each wait in its own
+    ``ckpt.save.to_host`` span (closed before the leaf is handed on)."""
+    for leaf in leaves:
+        with telemetry.span("ckpt.save.to_host"):
+            arr = np.asarray(leaf)
+        yield arr
+
+
 def save_pytree(store, prefix: str, step: int, tree, blocking: bool = True,
                 extra_meta: Optional[dict] = None):
     """Serialize a pytree.  Returns a handle with .wait() (async support).
 
-    Spans (``repro.telemetry``): ``ckpt.save`` around the call, with
-    ``ckpt.save.to_host``, then per leaf ``ckpt.save.serialize`` and
-    ``ckpt.save.put``, then ``ckpt.save.manifest`` (on the writer thread
-    when not blocking, still children of ``ckpt.save``)."""
+    Every device leaf's copy to the host is started before the first is
+    waited on.  A blocking save then serialises leaf ``i`` while the later
+    leaves are still in flight; a non-blocking one brings every leaf to the
+    host on the caller's thread before its writer starts, so the caller may
+    donate the state as soon as it returns.
+
+    Spans (``repro.telemetry``): ``ckpt.save`` around the call, with per
+    leaf ``ckpt.save.to_host`` (the wait for that leaf's copy; on the
+    caller's thread), ``ckpt.save.serialize`` and ``ckpt.save.put``, then
+    ``ckpt.save.manifest`` (on the writer thread when not blocking, still
+    children of ``ckpt.save``); counter ``ckpt.save.prefetch_bytes`` on
+    ``ckpt.save`` (the bytes of the leaves whose copy was started before
+    the first wait: 0 for a tree of host arrays)."""
     with telemetry.span("ckpt.save") as save_id:
         leaves, treedef = jax.tree.flatten(tree)
-        with telemetry.span("ckpt.save.to_host"):
-            host_leaves = [np.asarray(l) for l in leaves]   # device->host before thread
+        leaves = [l if isinstance(l, jax.Array) else np.asarray(l) for l in leaves]
+        jax.copy_to_host_async(leaves)
+        telemetry.count("ckpt.save.prefetch_bytes",
+                        sum(l.nbytes for l in leaves if isinstance(l, jax.Array)))
         meta = {
             "treedef": str(treedef),
             "n_leaves": len(leaves),
             "step": step,
-            "shapes": [list(l.shape) for l in host_leaves],
-            "dtypes": [str(l.dtype) for l in host_leaves],
+            "shapes": [list(l.shape) for l in leaves],
+            "dtypes": [str(l.dtype) for l in leaves],
             "keys": [k for k, _ in _leaf_paths(tree)],
             "extra": extra_meta or {},
         }
+        host_leaves = _to_host(leaves)
+        if not blocking:
+            host_leaves = list(host_leaves)   # device->host before the thread
 
         def write():
             base = f"{prefix}/step_{step:08d}"

@@ -321,6 +321,51 @@ def test_other_dtype_template_converts_and_counts(store, tmp_path, elastic):
                                     for leaf, c in zip(leaves_want, converted)]
 
 
+# ------------------------------------------------ the save's early transfers
+
+
+def _host_and_device_tree():
+    return {"w": jnp.arange(24, dtype=jnp.float32).reshape(4, 6) / 7,
+            "b": (jnp.arange(5, dtype=jnp.float32) / 3).astype(jnp.bfloat16),
+            "step": jnp.array(41, jnp.int32),
+            "host": np.arange(8, dtype=np.float32).reshape(2, 4) / 3}
+
+
+@pytest.mark.parametrize("blocking", [True, False], ids=["blocking", "writer_thread"])
+def test_save_overlaps_transfers_and_writes(store, tmp_path, blocking):
+    """A save starts every device leaf's copy to the host before it waits on
+    one, and counts those bytes alone (a numpy leaf has no copy to start).
+    Blocking, each leaf is waited on just before it is serialised: leaf
+    ``i``'s ``to_host`` ends before its ``serialize`` starts and before
+    leaf ``i+1``'s ``to_host`` starts, so no two of them overlap.  Not
+    blocking, every wait ends before the writer serialises a leaf.  Either
+    way the round trip is bitwise."""
+    t = _host_and_device_tree()
+    _, recs = _recorded(
+        tmp_path, lambda: save_pytree(store, "ckpt", 6, t, blocking=blocking).wait())
+    like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
+    out, step, _ = restore_pytree(store, "ckpt", like)
+    assert step == 6
+    for a, b in zip(jax.tree.leaves(t), jax.tree.leaves(out)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+
+    (save,) = [r for r in recs if r.name == "ckpt.save"]
+    device_leaves = {k: v for k, v in t.items() if isinstance(v, jax.Array)}
+    assert save.counts["ckpt.save.prefetch_bytes"] == tree_bytes(device_leaves)
+    assert tree_bytes(device_leaves) < tree_bytes(t)
+    n = len(jax.tree.leaves(t))
+    to_host = [r for r in recs if r.name == "ckpt.save.to_host"]
+    serialize = [r for r in recs if r.name == "ckpt.save.serialize"]
+    assert len(to_host) == len(serialize) == n
+    assert all(r.parent == save.id for r in to_host + serialize)
+    if blocking:
+        interleaved = [r for pair in zip(to_host, serialize) for r in pair]
+        assert all(a.t1 <= b.t0 for a, b in zip(interleaved, interleaved[1:]))
+    else:
+        assert max(r.t1 for r in to_host) <= min(r.t0 for r in serialize)
+
+
 class _AlignedStore:
     """A host-memory store whose leaves are read-only, 64-byte aligned
     buffers: the alignment at which the CPU backend adopts a host array
@@ -354,7 +399,9 @@ def test_donated_step_leaves_the_stored_snapshot_intact(tmp_path, kind):
     """A restored state may be a view of the store's bytes on the host; the
     train step donates its state, and that must never write into a stored
     snapshot.  After one donated step, a second restore of the same step
-    gives the first one's bits, and the stored leaves are unchanged."""
+    gives the first one's bits, and the stored leaves are unchanged.  A
+    non-blocking save that a donated step follows at once stores the state
+    as it was before that step."""
     store = LocalObjectStore(str(tmp_path / "s")) if kind == "local" else _AlignedStore()
     saver = _mamba2_trainer(store, seed=1)
     saver.step = 3
@@ -377,6 +424,18 @@ def test_donated_step_leaves_the_stored_snapshot_intact(tmp_path, kind):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(_bits(a), _bits(b))
     assert {k: bytes(store.get(k)) for k in leaf_keys} == stored
+
+    # device copies: reading the live leaves would bring them to the host first
+    before = [np.asarray(x) for x in jax.tree.leaves(jax.tree.map(jnp.copy, tr.state))]
+    tr.save(blocking=False)
+    tr.run_steps(1)
+    tr.ckpt.wait()
+    assert tr.step == 5
+    saved = _mamba2_trainer(store, seed=0)
+    assert saved.restore(step=4) == 4
+    for a, b in zip(before, jax.tree.leaves(saved.state)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(_bits(a), _bits(b))
 
 
 class _ManifestCountingStore(LocalObjectStore):

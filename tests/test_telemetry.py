@@ -143,7 +143,7 @@ def small_tree():
             "opt": {"step": jnp.zeros((), jnp.int32), "m": jnp.full((3, 2), 0.5)}}
 
 
-SAVE_PER_LEAF = ("ckpt.save.serialize", "ckpt.save.put")
+SAVE_PER_LEAF = ("ckpt.save.to_host", "ckpt.save.serialize", "ckpt.save.put")
 RESTORE_PER_LEAF = ("ckpt.restore.get", "ckpt.restore.decode", "ckpt.restore.to_device")
 
 
@@ -161,8 +161,7 @@ def test_checkpoint_save_and_restore_spans(recorder, tmp_path, blocking):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     recs = telemetry.records()
     (save,), (restore,) = by_name(recs, "ckpt.save"), by_name(recs, "ckpt.restore")
-    for name in ("ckpt.save.to_host", "ckpt.save.manifest"):
-        assert [r.parent for r in by_name(recs, name)] == [save.id]
+    assert [r.parent for r in by_name(recs, "ckpt.save.manifest")] == [save.id]
     for name in SAVE_PER_LEAF:
         assert [r.parent for r in by_name(recs, name)] == [save.id] * n
     assert [r.parent for r in by_name(recs, "ckpt.restore.manifest")] == [restore.id]
@@ -171,6 +170,10 @@ def test_checkpoint_save_and_restore_spans(recorder, tmp_path, blocking):
 
     def total(key):
         return sum(r.counts.get(key, 0) for r in recs)
+
+    # every leaf is a jax array: each one's copy to the host starts first
+    assert save.counts["ckpt.save.prefetch_bytes"] == tree_bytes(tree)
+    assert total("ckpt.save.prefetch_bytes") == tree_bytes(tree)
 
     # every saved dtype is the template's: each leaf decodes in place
     assert [r.counts.get("ckpt.host_copy_bytes") for r in by_name(recs, "ckpt.restore.decode")
@@ -223,4 +226,7 @@ def test_trainer_spans_are_the_programs_own(recorder, tmp_path):
     (first,) = by_name(recs, "trainer.init_state")
     (save,) = by_name(recs, "ckpt.save")
     assert first.parent is None and first.t1 <= save.t0
+    # the save starts the copy of the whole state before it waits on a leaf
+    assert save.counts["ckpt.save.prefetch_bytes"] == tree_bytes(tr.state) > 0
+    assert len(by_name(recs, "ckpt.save.to_host")) == len(jax.tree.leaves(tr.state))
     assert first.counts.get("/jax/core/compile/jaxpr_trace_duration", 0) > 0
